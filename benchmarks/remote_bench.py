@@ -22,6 +22,9 @@ Two further sweeps:
                       context: the serialize/channel/deserialize overlap
                       the kv_stream_* framing buys (pre-streaming,
                       serialize was ~86-89% of the remote wall clock).
+                      The child only decodes frames and runs on the host
+                      CPU (``JAX_PLATFORMS=cpu``): an accelerator belongs
+                      to one process, and this one already holds it.
   wire frontier     — bytes vs prediction agreement (vs the fp32 wire) for
                       fp16 / int8 / the adaptive per-layer plan: the plan
                       must sit at int8-or-fewer bytes at matched quality.
@@ -196,10 +199,17 @@ def bench_streaming_overlap(session, cfg, batch) -> list:
     srv = socket.socket()
     srv.bind(("127.0.0.1", 0))
     srv.listen(1)
+    srv.settimeout(120.0)
     child = subprocess.Popen(
         [sys.executable, "-c", _RX_CHILD.format(paths=paths),
-         str(srv.getsockname()[1])])
-    conn, _ = srv.accept()
+         str(srv.getsockname()[1])],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    try:
+        conn, _ = srv.accept()
+    except socket.timeout:
+        child.kill()
+        raise RuntimeError(f"receiver child never connected (exit code "
+                           f"{child.wait()})") from None
     conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
     ch = SocketChannel(conn)
     rows = []

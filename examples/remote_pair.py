@@ -1,13 +1,17 @@
-"""Two-process KVComm: sender and receiver in SEPARATE processes, selected
-KV crossing a real TCP socket through the framed remote codec.
+"""Remote KVComm: sender and receiver behind a real TCP socket, selected KV
+crossing it through the framed remote codec.
 
-The parent process plays the sender (and runs the in-process
-``InMemoryTransport`` reference); a spawned child process runs
-``repro.launch.remote_serve server`` with the receiver model.  The same
-calibrated, frozen layer selection drives both paths, so with a lossless
-fp32 wire the remote predictions must be IDENTICAL to the in-process ones —
-``--self-test`` asserts exactly that, plus the payload-bytes-vs-analytics
-equality, and exits non-zero on any mismatch (the CI socket smoke test).
+This process plays the sender (and runs the in-process
+``InMemoryTransport`` reference); the receiver is a ``KVServer`` whose
+accept loop runs on a thread of the same process.  One process, because an
+accelerator belongs to one process at a time: a server child would need
+the chip this process already holds.  (``repro.launch.remote_serve``
+runs the two halves as separate processes where each has its own device.)
+The same calibrated, frozen layer selection drives both paths, so with a
+lossless fp32 wire the remote predictions must be IDENTICAL to the
+in-process ones — ``--self-test`` asserts exactly that, plus the
+payload-bytes-vs-analytics equality, and exits non-zero on any mismatch
+(the CI socket smoke test).
 
     PYTHONPATH=src python examples/remote_pair.py --self-test
 """
@@ -15,7 +19,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -27,29 +30,9 @@ from repro.core import kv_wire_bytes
 from repro.core.types import KVCommConfig
 from repro.data.synthetic import SyntheticTask, TaskConfig
 from repro.launch.pairs import load_pair
-from repro.launch.remote_serve import KVClient
-
-_SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+from repro.launch.remote_serve import KVClient, KVServer
 
 ITEMSIZE = {"float32": 4, "float16": 2, "bfloat16": 2}
-
-
-def spawn_server() -> "tuple[subprocess.Popen, int]":
-    """Start the receiver process; returns (proc, bound port)."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "repro.launch.remote_serve", "server",
-         "--port", "0"],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-        env=env)
-    while True:
-        line = proc.stdout.readline()
-        if not line:
-            raise RuntimeError("server exited before announcing its port")
-        print(f"[server] {line.rstrip()}")
-        if line.startswith("PORT "):
-            return proc, int(line.split()[1])
 
 
 def main() -> int:
@@ -65,8 +48,6 @@ def main() -> int:
                          "analytics; non-zero exit on mismatch")
     args = ap.parse_args()
 
-    # the parent loads (and, cold, quick-trains + caches) the pair FIRST,
-    # so the child restores the cached checkpoint instead of retraining
     cfg, tok, sender_params, receiver_params = load_pair()
     task = SyntheticTask(tok, TaskConfig("retrieval", num_facts=6, seed=42))
     batch = task.batch(args.requests)
@@ -86,9 +67,10 @@ def main() -> int:
     print(f"in-process preds : {ref_toks[:, 0]}")
 
     # remote run: same frozen selection, KV over a real socket
-    proc, port = spawn_server()
+    server = KVServer(Agent("receiver", cfg, receiver_params, tok))
+    server.start()
     try:
-        client = KVClient.connect("127.0.0.1", port)
+        client = KVClient.connect(server.host, server.port)
         try:
             sent = client.share(session.sender, batch["context"], kvcfg,
                                 select, wire_dtype=args.wire_dtype)
@@ -97,10 +79,7 @@ def main() -> int:
         finally:
             client.close()
     finally:
-        try:
-            proc.wait(timeout=30)
-        except subprocess.TimeoutExpired:
-            proc.kill()
+        server.stop()
     print(f"remote preds     : {remote_toks[:, 0]}")
 
     M = int(np.asarray(select).sum())

@@ -65,8 +65,10 @@ def extract_states(cfg: ModelConfig, cache):
 def _sender_prefill_jit(params, cfg, context_tokens, extra):
     B, Sc = context_tokens.shape
     cache = tfm.init_cache(cfg, B, Sc)
+    # only the cache leaves: never a (B, Sc, vocab) logits tensor, which at
+    # a 128k vocabulary outweighs the exported KV
     out = tfm.apply_model(params, cfg, context_tokens, mode="cached",
-                          cache=cache, extra=extra)
+                          cache=cache, extra=extra, logits_mode="last")
     return extract_kv(cfg, out.cache), extract_states(cfg, out.cache)
 
 
@@ -414,7 +416,17 @@ def ragged_decode_step(params, cfg: ModelConfig, tokens, cache,
 
 def generate(params, cfg: ModelConfig, query_tokens, shared=None,
              max_new: int = 32, extra=None, stop_token: int = -1):
-    """Greedy generation. Returns (tokens (B, max_new), final cache)."""
+    """Greedy generation. Returns (tokens (B, max_new), final cache).
+
+    Prefill and the whole decode scan are ONE jitted program, compiled once
+    per (query shape, prefix layout, max_new) — a served path calls this
+    per request, and re-tracing the scan each time would recompile it."""
+    return _generate_jit(params, cfg, query_tokens, shared, extra,
+                         max_new=max_new)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "max_new"))
+def _generate_jit(params, cfg, query_tokens, shared, extra, max_new):
     out = receiver_prefill(params, cfg, query_tokens, shared,
                            max_new=max_new, extra=extra)
     cache = out.cache

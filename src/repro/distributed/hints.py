@@ -11,10 +11,12 @@ qwen1.5-110B train_4k — EXPERIMENTS.md §Perf iteration 2). These explicit
 seq-local so this is free) — which forces the intended ZeRO-3 behaviour.
 
 The hints are a no-op unless a launcher installs the mesh axes via
-``set_axes`` (tests and CPU serving never see them).
+``set_axes`` (tests and CPU serving never see them); axis sizes come from
+the mesh the launcher made current with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -57,18 +59,8 @@ def shard_logits(x):
 
 
 def _size(axis) -> int:
-    import numpy as np
-    mesh = None
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return 1
-    if mesh is None or mesh.empty:
-        return 1
-    if isinstance(axis, (tuple, list)):
-        n = 1
-        for a in axis:
-            n *= mesh.shape[a]
-        return n
-    return mesh.shape[axis]
+    names = axis if isinstance(axis, (tuple, list)) else (axis,)
+    return math.prod(mesh.shape[a] for a in names)

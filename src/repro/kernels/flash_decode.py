@@ -30,7 +30,7 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    len_ref,                        # (1, 1) int32 in SMEM-ish block
+    len_ref,                        # (B,) int32 SMEM (scalar prefetch)
     q_ref,                          # (1, 1, G, d)
     k_ref, v_ref,                   # (1, 1, blk_k, d)
     o_ref, m_out_ref, l_out_ref,    # (1,1,G,d), (1,1,G,1), (1,1,G,1)
@@ -42,6 +42,7 @@ def _decode_kernel(
     scale: float,
     normalize: bool,
 ):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -51,7 +52,7 @@ def _decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[b]
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, d)
     k = k_ref[0, 0].astype(jnp.float32)                  # (blk_k, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -108,34 +109,37 @@ def _call(q, k, v, kv_len, *, window, blk_k, scale, normalize, interpret):
     kernel = functools.partial(
         _decode_kernel, blk_k=blk_k, seq_kv=Skv, window=window, scale=scale,
         normalize=normalize)
-    lens = kv_len.reshape(B, 1).astype(jnp.int32)
-    out, m, l = pl.pallas_call(
-        kernel,
+    # kv_len rides in SMEM via scalar prefetch (see ragged_decode)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, _: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, ik, _: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, ik, _: (b, h, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda b, h, ik: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, D),
-                                 jnp.float32 if not normalize else q.dtype),
-            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, _: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, h, ik, _: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, G, 1), lambda b, h, ik, _: (b, h, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    out, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hkv, G, D),
+                                 jnp.float32 if not normalize else q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
+        ],
         interpret=interpret,
-    )(lens, q, k, v)
+    )(kv_len.astype(jnp.int32), q, k, v)
     return out, m[..., 0], l[..., 0]
 
 

@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These handle layout (B,S,H,D) <-> (B,H,S,D), padding to block multiples, and
-the interpret-mode switch (this container is CPU-only; TPU is the target).
+the interpret-mode switch: ``interpret=None`` compiles the kernel with Mosaic
+on a TPU backend and runs the Pallas interpreter everywhere else.
 The pure-jnp oracles live in ``ref.py``; ``tests/test_kernels.py`` sweeps
 shapes and dtypes asserting allclose between the two.
 """
@@ -20,6 +21,10 @@ from repro.kernels.flash_decode import flash_decode, flash_decode_partials
 # (handles its own D/blk padding — see kernels/ragged_decode.py)
 from repro.kernels.ragged_decode import ragged_decode  # noqa: F401
 from repro.kernels.rwkv_scan import wkv6
+
+
+def _interpret(flag: Optional[bool]) -> bool:
+    return jax.default_backend() != "tpu" if flag is None else flag
 
 
 def _pad_to(x, axis, mult):
@@ -44,7 +49,7 @@ def flash_attention(
     collect_mass: bool = False,
     blk_q: int = 128,
     blk_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """(B, S, H, D)-layout flash attention with KVComm prefix semantics.
 
@@ -72,7 +77,8 @@ def flash_attention(
     out, mass = flash_attention_bhsd(
         qb, kb, vb, context_len=context_len, q_offset=q_offset,
         causal=causal, window=window, collect_mass=collect_mass,
-        blk_q=blk_q, blk_k=blk_k, scale=scale, interpret=interpret)
+        blk_q=blk_q, blk_k=blk_k, scale=scale,
+        interpret=_interpret(interpret))
     out = jnp.moveaxis(out[:, :, :Sq, :D], 1, 2)
     if mass is not None:
         mass = jnp.mean(mass[:, :, :Sq], axis=(1, 2))
@@ -82,7 +88,7 @@ def flash_attention(
 @functools.partial(
     jax.jit, static_argnames=("window", "blk_k", "interpret"))
 def decode_attention(q, k, v, kv_len, *, window=None, blk_k=256,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """One-token decode over a long cache. q: (B, Hq, D); k/v (B, S, Hkv, D).
     Pads S to the kv block size; padding is masked by kv_len."""
     S = k.shape[1]
@@ -98,14 +104,14 @@ def decode_attention(q, k, v, kv_len, *, window=None, blk_k=256,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, dpad)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dpad)))
     out = flash_decode(q, k, v, kv_len, window=window, blk_k=blk_k,
-                       scale=scale, interpret=interpret)
+                       scale=scale, interpret=_interpret(interpret))
     return out[..., :D]
 
 
 @functools.partial(
     jax.jit, static_argnames=("window", "blk_k", "interpret"))
 def decode_attention_partials(q, k, v, kv_len, *, window=None, blk_k=256,
-                              interpret: bool = True):
+                              interpret: Optional[bool] = None):
     """Shard-local flash-decode partials (o, m, l) for the sequence-parallel
     combine (``ref.combine_decode_partials``)."""
     S = k.shape[1]
@@ -122,15 +128,16 @@ def decode_attention_partials(q, k, v, kv_len, *, window=None, blk_k=256,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dpad)))
     o, m, l = flash_decode_partials(q, k, v, kv_len, window=window,
                                     blk_k=blk_k, scale=scale,
-                                    interpret=interpret)
+                                    interpret=_interpret(interpret))
     return o[..., :D], m, l
 
 
 @functools.partial(jax.jit, static_argnames=("blk_t", "interpret"))
 def wkv6_scan(r, k, v, w, u, state, *, blk_t: int = 32,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     """Chunked RWKV6 recurrence; layout (B, S, H, hd) like the oracle."""
-    return wkv6(r, k, v, w, u, state, blk_t=blk_t, interpret=interpret)
+    return wkv6(r, k, v, w, u, state, blk_t=blk_t,
+                interpret=_interpret(interpret))
 
 
 __all__ = ["flash_attention", "decode_attention",

@@ -43,9 +43,20 @@ NEG_INF = -1e30
 _LANE = 128
 
 
+def segment_mask(rk, kv_len, pfx, *, prefix_len: int, seq_kv: int):
+    """The two-segment validity mask of cache slots ``rk`` for one row:
+    ``rk < prefix_len ? rk < pfx : rk < kv_len``, and never past the real
+    KV length ``seq_kv``.  It selects the row's integer LIMIT and compares
+    once — Mosaic lowers an int32 select, but not a select between two
+    bool vectors."""
+    limit = jnp.where(rk < prefix_len, pfx, kv_len) if prefix_len > 0 \
+        else kv_len
+    return (rk < limit) & (rk < seq_kv)
+
+
 def _ragged_decode_kernel(
-    len_ref,                        # (1, 1) int32 — total valid (prefix+self)
-    pfx_ref,                        # (1, 1) int32 — real prefix entries
+    len_ref,                        # (B,) int32 SMEM — total valid entries
+    pfx_ref,                        # (B,) int32 SMEM — real prefix entries
     q_ref,                          # (1, 1, G, d)
     k_ref, v_ref,                   # (1, 1, blk_k, d)
     o_ref,                          # (1, 1, G, d)
@@ -56,6 +67,7 @@ def _ragged_decode_kernel(
     prefix_len: int,
     scale: float,
 ):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -65,19 +77,15 @@ def _ragged_decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[b]
     q = q_ref[0, 0].astype(jnp.float32)                  # (G, d)
     k = k_ref[0, 0].astype(jnp.float32)                  # (blk_k, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     G = s.shape[0]
     rk = ik * blk_k + jax.lax.broadcasted_iota(jnp.int32, (G, blk_k), 1)
-    if prefix_len > 0:
-        pfx = pfx_ref[0, 0]
-        allow = jnp.where(rk < prefix_len, rk < pfx, rk < kv_len)
-    else:
-        allow = rk < kv_len
-    allow = allow & (rk < seq_kv)
+    allow = segment_mask(rk, kv_len, pfx_ref[b], prefix_len=prefix_len,
+                         seq_kv=seq_kv)
     s = jnp.where(allow, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -116,32 +124,33 @@ def _call(q, k, v, kv_len, prefix_lens, *, prefix_len, blk_k, scale,
     kernel = functools.partial(
         _ragged_decode_kernel, blk_k=blk_k, seq_kv=Skv,
         prefix_len=prefix_len, scale=scale)
-    lens = kv_len.reshape(B, 1).astype(jnp.int32)
-    pfx = prefix_lens.reshape(B, 1).astype(jnp.int32)
-    (out,) = pl.pallas_call(
-        kernel,
+    # the per-row lengths ride in SMEM via scalar prefetch (a (1, 1) VMEM
+    # block of a (B, 1) array violates Mosaic's (8, 128) tiling rule);
+    # index maps receive the prefetched refs as trailing arguments
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, ik: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, blk_k, D),
+                         lambda b, h, ik, *_: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, blk_k, D),
+                         lambda b, h, ik, *_: (b, h, ik, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        ],
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, ik, *_: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
-    )(lens, pfx, q, k, v)
-    return out
+    )(kv_len.astype(jnp.int32), prefix_lens.astype(jnp.int32), q, k, v)
 
 
 def ragged_decode(q, k, v, kv_len, prefix_lens=None, *, prefix_len: int = 0,

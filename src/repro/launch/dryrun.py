@@ -124,7 +124,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
             in_sh = shardings_for(cfg, mesh, shape, args_spec)
         donate = (0,) if shape.mode == "train" else \
                  ((2,) if shape.mode == "decode" else ())
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh, donate_argnums=donate)
             lowered = jitted.lower(*args_spec)
             rec["lower_s"] = round(time.time() - t0, 1)

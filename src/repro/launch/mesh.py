@@ -14,9 +14,13 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """Auto (compiler-propagated) axes: the dry-run pins activations with
+    ``distributed.hints`` constraints, which an Explicit mesh would treat
+    as assertions instead."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -7,7 +7,15 @@ the same pair without ``sys.path`` games.  Checkpoints are produced by
 ``examples/train_comm_pair.py`` (which imports these definitions) and land
 in ``experiments/ckpt/{base,sender,receiver}.npz``; when absent,
 ``load_pair`` quick-trains a single model for both roles so every entry
-point still runs end to end.
+point still runs end to end.  That 8-layer float32 stand-in is the CPU
+default.
+
+``load_pair(arch=...)`` instead serves a registry configuration at its
+published widths (bfloat16, full vocabulary) with random weights made from a
+seed on the device — the accelerator path, which never reads
+``experiments/``.  Sender and receiver share one parameter tree: the
+paper's pair #6 is two fine-tunes of one base, and two copies of a
+3B-parameter model would not fit one 16 GB chip.
 """
 from __future__ import annotations
 
@@ -92,10 +100,15 @@ def _quick_train(cfg, tok, steps: int = 1200, ckpt_name: str = "base"):
 _CACHE: dict = {}
 
 
-def load_pair() -> Tuple[ModelConfig, SymbolTokenizer, Any, Any]:
+def load_pair(arch=None, seed: int = 0
+              ) -> Tuple[ModelConfig, SymbolTokenizer, Any, Any]:
     """(cfg, tok, sender_params, receiver_params). Uses the trained
     checkpoints when available, else quick-trains a single model for both
-    roles (the protocol is still exercised end to end)."""
+    roles (the protocol is still exercised end to end).  With ``arch`` (a
+    registry name) the pair is that config at full width with weights drawn
+    from ``seed``; see ``seeded_pair``."""
+    if arch is not None:
+        return seeded_pair(arch, seed)
     if "pair" in _CACHE:
         return _CACHE["pair"]
     cfg, tok = pair_config(), pair_tokenizer()
@@ -112,6 +125,28 @@ def load_pair() -> Tuple[ModelConfig, SymbolTokenizer, Any, Any]:
         sender = receiver = _quick_train(cfg, tok)
     _CACHE["pair"] = (cfg, tok, sender, receiver)
     return _CACHE["pair"]
+
+
+def seeded_pair(arch, seed: int = 0
+                ) -> Tuple[ModelConfig, SymbolTokenizer, Any, Any]:
+    """The registry config ``arch`` (a name, or a ``ModelConfig`` such as a
+    ``reduced()`` cut for CPU rehearsals) unchanged — published widths, its
+    own dtype and vocabulary — with one parameter tree initialized by a
+    jitted ``init_params`` on the default device, used for both roles.  The
+    ``SymbolTokenizer`` ids are a subset of the config's vocabulary."""
+    cfg = get_config(arch) if isinstance(arch, str) else arch
+    key = ("seeded", cfg, seed)
+    if key not in _CACHE:
+        from repro.models import transformer as tfm
+        tok = pair_tokenizer()
+        if tok.vocab_size > cfg.vocab_size:
+            raise ValueError(f"{cfg.name}: vocabulary {cfg.vocab_size} "
+                             f"cannot hold the task tokenizer's "
+                             f"{tok.vocab_size}")
+        init = jax.jit(tfm.init_params, static_argnums=0)
+        params = init(cfg, jax.random.PRNGKey(seed))
+        _CACHE[key] = (cfg, tok, params, params)
+    return _CACHE[key]
 
 
 def _param_template(cfg: ModelConfig):

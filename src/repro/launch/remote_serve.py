@@ -582,15 +582,15 @@ class KVClient:
 # ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
-def _load_agents() -> Tuple[Agent, Agent, object]:
+def _load_agents(args) -> Tuple[Agent, Agent, object]:
     from repro.launch.pairs import load_pair
-    cfg, tok, sender, receiver = load_pair()
+    cfg, tok, sender, receiver = load_pair(args.arch)
     return (Agent("sender", cfg, sender, tok),
             Agent("receiver", cfg, receiver, tok), tok)
 
 
 def run_server(args) -> None:
-    _, receiver, _ = _load_agents()
+    _, receiver, _ = _load_agents(args)
     store = None
     if args.pool_mb > 0:
         from repro.store import PageStore
@@ -615,7 +615,7 @@ def run_server(args) -> None:
 
 def run_client(args) -> None:
     from repro.data.synthetic import SyntheticTask, TaskConfig
-    sender, _, tok = _load_agents()
+    sender, _, tok = _load_agents(args)
     task = SyntheticTask(tok, TaskConfig("retrieval", num_facts=6, seed=42))
     batch = task.batch(args.requests)
     kvcfg = KVCommConfig(ratio=args.ratio, selector="prior_only")
@@ -690,7 +690,14 @@ def main(argv=None) -> None:
                    help="per-read/write socket timeout in seconds (raises "
                         "the typed ChannelTimeoutError instead of hanging "
                         "on a stalled peer)")
+    for p in (s, c):
+        p.add_argument("--arch", default=None,
+                       help="registry config at full width with seeded "
+                            "weights (both sides must agree); default: the "
+                            "trained CPU stand-in pair")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.role == "server":
         run_server(args)
     else:

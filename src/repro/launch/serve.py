@@ -16,6 +16,10 @@ payload; the wire defaults to int8 (characterized across the task suite in
 default).
 
     PYTHONPATH=src python -m repro.launch.serve --requests 32 --ratio 0.5
+
+``--arch llama3.2-3b-pair`` serves that registry config at its published
+widths in bfloat16 with seeded random weights (see ``pairs.seeded_pair``) —
+the accelerator path; accuracy is then chance-level by construction.
 """
 from __future__ import annotations
 
@@ -31,17 +35,24 @@ from repro.data.synthetic import SyntheticTask, TaskConfig
 from repro.launch.pairs import load_pair
 from repro.serving.scheduler import (Scheduler, SchedulerConfig, accuracy,
                                      make_requests, serve_serial)
+from repro.utils.compile_cache import enable_compile_cache
 
 
-def build_requests(tok, task: str, n: int, max_new: int):
+def build_requests(tok, task: str, n: int, max_new: int,
+                   context_tile: int = 1):
     """A mixed-length request stream: contexts sampled across fact counts
-    so prefix lengths are ragged (what continuous batching is for)."""
+    so prefix lengths are ragged (what continuous batching is for).
+    ``context_tile`` repeats each context that many times — long shared
+    documents from the same generator, still ragged."""
     batches = []
     per = -(-n // 3)   # ceil: never serve fewer than asked
     for i, nf in enumerate((4, 6, 8)):
         t = SyntheticTask(tok, TaskConfig(task, num_facts=nf, seed=42 + i))
         batches.append(t.batch(per))
-    return make_requests(batches, max_new=max_new, pad=tok.PAD)[:n]
+    reqs = make_requests(batches, max_new=max_new, pad=tok.PAD)[:n]
+    for r in reqs:
+        r.context = np.tile(r.context, context_tile)
+    return reqs
 
 
 def main() -> None:
@@ -69,9 +80,14 @@ def main() -> None:
                     help="per-step decode attention: masked-dense "
                          "reference or the fused Pallas ragged kernel "
                          "(interpret-mode off-TPU)")
+    ap.add_argument("--arch", default=None,
+                    help="registry config served at full width with seeded "
+                         "weights (e.g. llama3.2-3b-pair); default: the "
+                         "trained CPU stand-in pair")
     args = ap.parse_args()
 
-    cfg, tok, sender, receiver = load_pair()
+    enable_compile_cache()
+    cfg, tok, sender, receiver = load_pair(args.arch)
     transport = (SerializedTransport(args.wire_dtype)
                  if args.transport == "serialized" else InMemoryTransport())
     session = CommSession(Agent("sender", cfg, sender, tok),

@@ -24,7 +24,6 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import LayerSpec, ModelConfig
 from repro.distributed import hints
@@ -150,37 +149,20 @@ def _run_partition(attn_i: int, n: int, layers: Tuple[int, ...]):
     return sel, unsel, tuple(segments)
 
 
-# Host-side gather cache: selection-partitioned parameter stacks keyed by
-# (buffer id of the first leaf, local index tuple). Only populated for
-# concrete arrays (eager decode); under jit the gather is traced once per
-# compile and amortized by the jit cache. The source leaf is pinned in the
-# value so a garbage-collected buffer cannot alias a stale id.
-_PART_CACHE: Dict[Tuple[int, Tuple[int, ...]], Tuple[Any, Any]] = {}
-
-
-def _gather_layers(tree, idx: Tuple[int, ...]):
-    """Static-index gather of a stacked-parameter (or cache) pytree along
-    the leading layer axis, identity-cached per selection bitmask."""
-    leaves = jax.tree.leaves(tree)
-    if not leaves:
-        return tree
-    if idx == tuple(range(leaves[0].shape[0])):
-        return tree   # full stack in order: nothing to gather
-    key = (id(leaves[0]), idx)
-    hit = _PART_CACHE.get(key)
-    if hit is not None and hit[0] is leaves[0]:
-        return hit[1]   # stored values are always concrete: safe anywhere
-    ia = np.asarray(idx, np.int32)
-    out = jax.tree.map(lambda a: a[ia], tree)
-    # cache only fully-concrete results: with ANY trace active (jit, scan,
-    # grad) ops are staged and `out` holds tracers, which must never
-    # outlive their trace
-    if not any(isinstance(leaf, jax.core.Tracer)
-               for leaf in jax.tree.leaves((tree, out))):
-        if len(_PART_CACHE) > 64:
-            _PART_CACHE.clear()
-        _PART_CACHE[key] = (leaves[0], out)
-    return out
+def _indexed_layer_body(body, stack):
+    """Wrap a layer body so it reads its parameters out of the run's FULL
+    stacked tree by layer index (``per["layer"]``).  The partitioned scans
+    of the packed fast path then never materialize a gathered copy of a
+    parameter stack: a 3B-parameter model's selected + unselected stacks
+    would otherwise cost a second copy of every layer weight per call."""
+    def indexed(x, per):
+        per = dict(per)
+        i = per.pop("layer")
+        per["params"] = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            stack)
+        return body(x, per)
+    return indexed
 
 
 def _is_packed_entry(run_cache) -> bool:
@@ -500,17 +482,16 @@ def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
                            backend="reference"):
     """Execute one attention run under the selection-specialized fast path.
 
-    The run's stacked params are partitioned (static, host-gathered and
-    cached per selection bitmask) into a selected stack whose cache carries
-    the sender prefix and an unselected stack whose cache is prefix-free;
-    layer order is preserved by scanning maximal contiguous same-status
-    segments in sequence. Prefix attention FLOPs therefore scale with the
-    number of selected layers, not the run length, and the unselected
-    buffers never hold (or mask) prefix entries.
+    The run's cache is partitioned (static, per selection bitmask) into a
+    selected stack that carries the sender prefix and an unselected stack
+    that is prefix-free; layer order is preserved by scanning maximal
+    contiguous same-status segments in sequence, each reading its layers'
+    parameters from the unpartitioned stack by index. Prefix attention
+    FLOPs therefore scale with the number of selected layers, not the run
+    length, and the unselected buffers never hold (or mask) prefix entries.
     """
     sel, unsel, segments = _run_partition(attn_i, spec.count, shared.layers)
-    stacks = {"sel": (_gather_layers(run_p, sel), len(sel)),
-              "unsel": (_gather_layers(run_p, unsel), len(unsel))}
+    stack_len = {"sel": len(sel), "unsel": len(unsel)}
     cache_keys = ["k", "v", "ctx_valid"]
     if spec.cross_attn:
         cache_keys += ["xk", "xv"]
@@ -518,12 +499,10 @@ def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
     masses, hiddens = [], []
     aux = jnp.zeros((), jnp.float32)
     zero_unsel = shared.pos_mode == "zero_unselected"
+    j0 = 0   # the segment's first layer within the run
     for is_sel, s0, ln in segments:
         name = "sel" if is_sel else "unsel"
-        p_stack, stack_len = stacks[name]
-        whole = ln == stack_len
-        sub_p = p_stack if whole else jax.tree.map(
-            lambda a: a[s0:s0 + ln], p_stack)
+        whole = ln == stack_len[name]
         sub_cache = {kk: (run_cache[name][kk] if whole
                           else run_cache[name][kk][s0:s0 + ln])
                      for kk in cache_keys}
@@ -539,7 +518,7 @@ def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
         else:
             shift = 0 if (zero_unsel and not is_sel) else prefix_len
             shift_arr = jnp.full((ln,), shift, jnp.int32)
-        per = {"params": sub_p,
+        per = {"layer": jnp.arange(j0, j0 + ln),
                "pos_shift": shift_arr,
                "cache": sub_cache,
                "cache_len": jnp.broadcast_to(clen,
@@ -547,10 +526,12 @@ def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
         if prefix_lens is not None and is_sel:
             per["prefix_lens"] = jnp.broadcast_to(
                 prefix_lens[None], (ln,) + prefix_lens.shape)
-        body = _attn_layer_body(cfg, spec, "cached", pfx, collect_mass,
-                                enc_out, capture_hidden=capture_hidden,
-                                backend=backend)
+        body = _indexed_layer_body(
+            _attn_layer_body(cfg, spec, "cached", pfx, collect_mass,
+                             enc_out, capture_hidden=capture_hidden,
+                             backend=backend), run_p)
         x, ys = _run_scan(body, x, per, remat=False, unroll=cfg.scan_unroll)
+        j0 += ln
         aux = aux + jnp.sum(ys["aux"])
         if collect_mass:
             masses.append(ys["mass"])

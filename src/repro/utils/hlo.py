@@ -36,16 +36,9 @@ _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older releases return a one-element list of per-computation dicts;
-    newer ones return the dict directly. Either way, hand back a dict
-    (empty when XLA reports nothing).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA reports
+    nothing)."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_bytes(dtype: str, dims: str) -> int:

@@ -59,6 +59,33 @@ class TestRaggedDecodeKernel:
         np.testing.assert_allclose(np.asarray(out), np.asarray(rout),
                                    atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("seed", range(4))
+    def test_segment_mask_equals_bool_predicate(self, seed):
+        """The kernel's integer-select mask equals the two-segment bool
+        predicate ``rk < P ? rk < pfx : rk < kv_len`` (and ``rk < Skv``)
+        on every block of random rows, dead rows (kv_len = pfx = 0)
+        included."""
+        from repro.kernels.ragged_decode import segment_mask
+        rng = np.random.default_rng(seed)
+        for _ in range(16):
+            prefix_len = int(rng.integers(0, 24))
+            S = prefix_len + int(rng.integers(1, 40))
+            blk_k = int(rng.integers(1, 48))
+            B = int(rng.integers(1, 6))
+            kv_len = rng.integers(prefix_len, S + 1, B)
+            pfx = rng.integers(0, prefix_len + 1, B)
+            dead = rng.random(B) < 0.3
+            kv_len[dead], pfx[dead] = 0, 0
+            nk = -(-S // blk_k)
+            # (B, nk, blk_k): every block of every row, padded tail too
+            rk = jnp.arange(nk * blk_k, dtype=jnp.int32).reshape(
+                1, nk, blk_k)
+            kl = jnp.asarray(kv_len, jnp.int32)[:, None, None]
+            pf = jnp.asarray(pfx, jnp.int32)[:, None, None]
+            want = jnp.where(rk < prefix_len, rk < pf, rk < kl) & (rk < S)
+            got = segment_mask(rk, kl, pf, prefix_len=prefix_len, seq_kv=S)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     def test_prefix_free_matches_flash_decode_oracle(self):
         """With prefix_len=0 the two-segment mask degenerates to the plain
         ragged mask — the kernel must agree with decode_reference."""
