@@ -16,8 +16,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 SUITES = [
-    "kernels_bench",       # kernel microbenchmarks
-    "decode_bench",        # eager vs jitted donated decode (BENCH_decode)
     "serve_bench",         # continuous batching vs serial (BENCH_serve)
     "fig8_efficiency",     # paper Fig. 8 + §3.3 (analytic + measured)
     "table1_comm",         # paper Table 1

@@ -22,7 +22,7 @@ reference is asserted before its row is reported.
 
 Writes ``BENCH_serve.json`` at the repo root: tokens/s (serial, scheduled
 reference, scheduled pallas), TTFT p50, slot occupancy, speedup, per ratio
-in {0.3, 0.5} — the ratio axis shared with ``BENCH_decode.json``.
+in {0.3, 0.5}.
 """
 from __future__ import annotations
 

@@ -26,6 +26,7 @@ from repro import core
 from repro.configs.base import ModelConfig
 from repro.core.types import SharedKV
 from repro.models import transformer as tfm
+from repro.utils import spans
 
 
 @dataclass
@@ -47,8 +48,9 @@ class Agent:
                   ) -> Tuple[Any, Any, int]:
         """One forward pass over [BOS? context]; returns (kv, states, Sc)."""
         ctx = self.with_bos(context) if add_bos else np.asarray(context)
-        kv, states = core.sender_prefill(self.params, self.cfg,
-                                         jnp.asarray(ctx))
+        with spans.span(spans.SENDER_PREFILL):
+            kv, states = core.sender_prefill(self.params, self.cfg,
+                                             jnp.asarray(ctx))
         return kv, states, ctx.shape[1]
 
     def message(self, context: np.ndarray, n_tokens: int

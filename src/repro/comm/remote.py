@@ -61,6 +61,7 @@ from repro.core.layermap import LayerAssignment
 from repro.core.protocol import (gather_mapped, gather_selected,
                                  selected_layer_ids)
 from repro.core.types import KVCommConfig, SharedKV
+from repro.utils import spans
 from repro.comm.transport import (Transport, WirePlan, as_wire_plan,
                                   decode_wire, encode_wire, np_decode_wire,
                                   np_encode_wire,
@@ -1403,25 +1404,24 @@ class RemoteTransport(Transport):
         if self.chunk_bytes is not None:
             return self._ship_streamed(kvcfg, kv, select, states,
                                        state_select, assignment)
-        t0 = time.perf_counter()
-        frame, n_bytes, layer_count, prefix_len = encode_kv_transfer(
-            kvcfg, kv, select, states, state_select, assignment,
-            self.wire_dtype, self.packed)
-        t1 = time.perf_counter()
-        self.channel.write(frame)
-        kind, meta, arrays = read_frame(self.channel)
-        t2 = time.perf_counter()
+        clock = spans.WireClock()
+        with clock(spans.WIRE_ENCODE):
+            frame, n_bytes, layer_count, prefix_len = encode_kv_transfer(
+                kvcfg, kv, select, states, state_select, assignment,
+                self.wire_dtype, self.packed)
+        with clock(spans.WIRE_CHANNEL):
+            self.channel.write(frame)
+            kind, meta, arrays = read_frame(self.channel)
         if kind != "shared_kv":
             raise PayloadMismatchError(
                 f"expected a shared_kv frame, got {kind!r}")
-        shared, n_decoded = decode_kv_transfer(meta, arrays)
-        t3 = time.perf_counter()
+        with clock(spans.WIRE_DECODE):
+            shared, n_decoded = decode_kv_transfer(meta, arrays)
         self.log.append(TransferRecord(
             kind="kv", n_bytes=n_decoded, layers=layer_count,
             context_len=prefix_len,
             wire_dtype=wire_spec(self.wire_dtype),
-            serialize_s=t1 - t0, channel_s=t2 - t1, deserialize_s=t3 - t2,
-            frame_bytes=len(frame)))
+            frame_bytes=len(frame), **clock.fields()))
         return shared
 
     def _ship_streamed(self, kvcfg: KVCommConfig, kv, select, states,
@@ -1439,33 +1439,28 @@ class RemoteTransport(Transport):
                                 chunk_bytes=self.chunk_bytes, sid=sid)
         asm = KVStreamAssembler()
         frames = sender.frames()
-        ser_s = chan_s = deser_s = 0.0
+        clock = spans.WireClock()
         frame_bytes = 0
         out = None
         while out is None:
-            t0 = time.perf_counter()
-            try:
-                frame, _ = next(frames)
-            except StopIteration:   # pragma: no cover - assembler ends 1st
-                raise PayloadMismatchError(
-                    "KV stream exhausted before the end frame resolved")
-            t1 = time.perf_counter()
+            with clock(spans.WIRE_ENCODE):
+                try:
+                    frame, _ = next(frames)
+                except StopIteration:  # pragma: no cover - assembler ends 1st
+                    raise PayloadMismatchError(
+                        "KV stream exhausted before the end frame resolved")
             frame_bytes += len(frame)
-            self.channel.write(frame)
-            kind, meta, arrays = read_frame(self.channel)
-            t2 = time.perf_counter()
-            out = asm.feed(kind, meta, arrays)
-            t3 = time.perf_counter()
-            ser_s += t1 - t0
-            chan_s += t2 - t1
-            deser_s += t3 - t2
+            with clock(spans.WIRE_CHANNEL):
+                self.channel.write(frame)
+                kind, meta, arrays = read_frame(self.channel)
+            with clock(spans.WIRE_DECODE):
+                out = asm.feed(kind, meta, arrays)
         shared, n_bytes = out
         self.log.append(TransferRecord(
             kind="kv", n_bytes=n_bytes, layers=sender.layer_count,
             context_len=sender.prefix_len,
             wire_dtype=wire_spec(self.wire_dtype),
-            serialize_s=ser_s, channel_s=chan_s, deserialize_s=deser_s,
-            frame_bytes=frame_bytes))
+            frame_bytes=frame_bytes, **clock.fields()))
         return shared
 
     def _send(self, cfg: ModelConfig, kvcfg: KVCommConfig, kv, select,
@@ -1523,60 +1518,61 @@ class RemoteTransport(Transport):
             sel_mask = np.asarray(select)
             layer_count = selected_count(select)
         xid, self._xid = self._xid, self._xid + 1
-        t0 = time.perf_counter()
-        table, pages = split_payload(
-            payload, layers=layers, select=sel_mask,
-            page_len=self.store.page_len, wire_dtype=self.wire_dtype,
-            pos_mode=kvcfg.pos_mode, src_layers=src_layers)
-        by_id = {p.page_id: p for p in pages}
-        qframe = encode_page_query(xid, table)
-        t1 = time.perf_counter()
-        self.channel.write(qframe)
-        kind, meta, arrays = read_frame(self.channel)
-        t2 = time.perf_counter()
+        clock = spans.WireClock()
+        with clock(spans.WIRE_ENCODE):
+            table, pages = split_payload(
+                payload, layers=layers, select=sel_mask,
+                page_len=self.store.page_len, wire_dtype=self.wire_dtype,
+                pos_mode=kvcfg.pos_mode, src_layers=src_layers)
+            by_id = {p.page_id: p for p in pages}
+            qframe = encode_page_query(xid, table)
+        with clock(spans.WIRE_CHANNEL):
+            self.channel.write(qframe)
+            kind, meta, arrays = read_frame(self.channel)
         if kind != "page_query":
             raise PayloadMismatchError(
                 f"expected a page_query frame, got {kind!r}")
-        need_frame = self._paged_rx.handle_query(meta, arrays)
-        self.channel.write(need_frame)
-        kind, meta, _ = read_frame(self.channel)
+        # the receiver answers the query with the pages it lacks
+        with clock(spans.WIRE_DECODE):
+            need_frame = self._paged_rx.handle_query(meta, arrays)
+        with clock(spans.WIRE_CHANNEL):
+            self.channel.write(need_frame)
+            kind, meta, _ = read_frame(self.channel)
         if kind != "page_need":
             raise PayloadMismatchError(
                 f"expected a page_need frame, got {kind!r}")
-        _, need = decode_page_need(meta)
-        t3 = time.perf_counter()
-        dframe, _ = encode_page_data(
-            xid, [by_id[pid] for pid in need],
-            wire_dtype=self.wire_dtype, states=states,
-            state_select=state_select)
-        t4 = time.perf_counter()
-        self.channel.write(dframe)
-        kind, meta, arrays = read_frame(self.channel)
-        t5 = time.perf_counter()
+        with clock(spans.WIRE_ENCODE):
+            _, need = decode_page_need(meta)
+            dframe, _ = encode_page_data(
+                xid, [by_id[pid] for pid in need],
+                wire_dtype=self.wire_dtype, states=states,
+                state_select=state_select)
+        with clock(spans.WIRE_CHANNEL):
+            self.channel.write(dframe)
+            kind, meta, arrays = read_frame(self.channel)
         if kind != "page_data":
             raise PayloadMismatchError(
                 f"expected a page_data frame, got {kind!r}")
-        shared, table_rx, novel_bytes, state_bytes = \
-            self._paged_rx.handle_data(meta, arrays)
-        # handle_data left table_rx pinned; anything failing between here
-        # and a successful swap must release it or the refcounts leak
-        try:
-            if not self.packed:
-                shared = shared.to_dense()
-            self._swap_table(table_rx)
-        except BaseException:
-            self.store.release(table_rx)
-            raise
-        t6 = time.perf_counter()
+        with clock(spans.WIRE_DECODE):
+            shared, table_rx, novel_bytes, state_bytes = \
+                self._paged_rx.handle_data(meta, arrays)
+            # handle_data left table_rx pinned; anything failing between
+            # here and a successful swap must release it or the refcounts
+            # leak
+            try:
+                if not self.packed:
+                    shared = shared.to_dense()
+                self._swap_table(table_rx)
+            except BaseException:
+                self.store.release(table_rx)
+                raise
         self.log.append(TransferRecord(
             kind="kv",
             n_bytes=novel_bytes + table_rx.scale_nbytes + state_bytes,
             layers=layer_count, context_len=table.prefix_len,
             wire_dtype=wire_spec(self.wire_dtype),
-            serialize_s=(t1 - t0) + (t4 - t3),
-            channel_s=(t2 - t1) + (t5 - t4),
-            deserialize_s=(t3 - t2) + (t6 - t5),
             frame_bytes=len(qframe) + len(need_frame) + len(dframe),
+            **clock.fields(),
             pages_total=table.num_pages, pages_sent=len(need),
             pages_hit=table.num_pages - len(need)))
         return shared

@@ -35,6 +35,7 @@ from repro.comm.resilience import DegradationEvent, Resilience
 from repro.comm.transport import InMemoryTransport, Transport
 from repro.core.channel import TransferRecord, combine_senders
 from repro.core.types import KVCommConfig, SharedKV
+from repro.utils import spans
 
 # what the degradation ladder can catch: transport/protocol failures (incl.
 # RetriesExhaustedError and CircuitOpenError) and raw socket errors — never
@@ -333,11 +334,12 @@ class CommSession:
         assert not self.is_hetero, \
             "sender and receiver disagree on depth; use share_mapped " \
             "(or the 'hetero_kvcomm' method) with a LayerMap policy"
-        select = self.selection(kvcfg, scores=scores, key=key)
-        kv, states, _ = self.sender.export_kv(context)
-        state_select = self._state_selection(kvcfg, states)
-        shared = self._resilient_send(kvcfg, kv, select, states,
-                                      state_select, sync=sync, rid=rid)
+        with spans.span(spans.SHARE, rid=rid):
+            select = self.selection(kvcfg, scores=scores, key=key)
+            kv, states, _ = self.sender.export_kv(context)
+            state_select = self._state_selection(kvcfg, states)
+            shared = self._resilient_send(kvcfg, kv, select, states,
+                                          state_select, sync=sync, rid=rid)
         return shared, select
 
     def share_mapped(self, context: np.ndarray, kvcfg: KVCommConfig,
@@ -355,34 +357,35 @@ class CommSession:
         policy='identity' reproduces ``share`` bit-for-bit).
 
         Returns (receiver-side SharedKV, the LayerAssignment used)."""
-        src_select = self.side_selection("sender", kvcfg,
-                                         scores=src_scores, key=key)
-        if src_scores is None and key is not None:
-            src_scores = self._side_scores.get(("sender", key))
-        if dst_scores is None and key is not None:
-            dst_scores = self._side_scores.get(("receiver", key))
-        src_layers = core.selected_layer_ids(src_select)
-        assignment = core.get_layer_map(policy).assign(
-            src_layers,
-            num_src_layers=self.sender.cfg.attn_layer_count,
-            num_dst_layers=self.receiver.cfg.attn_layer_count,
-            src_scores=(None if src_scores is None
-                        else np.asarray(src_scores)),
-            dst_scores=(None if dst_scores is None
-                        else np.asarray(dst_scores)))
-        kv, states, _ = self.sender.export_kv(context)
-        if states is not None:
-            # SSM state sharing is positional (no mapping policy yet):
-            # only possible when both sides agree on SSM depth
-            from repro.core.protocol import _n_ssm
-            n_ssm = jax.tree.leaves(states)[0].shape[0]
-            if n_ssm != _n_ssm(self.receiver.cfg):
-                states = None
-        state_select = self._state_selection(kvcfg, states)
-        shared = self._resilient_send(kvcfg, kv, None, states, state_select,
-                                      assignment=assignment, sync=sync,
-                                      rid=rid)
-        return shared, assignment
+        with spans.span(spans.SHARE, rid=rid):
+            src_select = self.side_selection("sender", kvcfg,
+                                             scores=src_scores, key=key)
+            if src_scores is None and key is not None:
+                src_scores = self._side_scores.get(("sender", key))
+            if dst_scores is None and key is not None:
+                dst_scores = self._side_scores.get(("receiver", key))
+            src_layers = core.selected_layer_ids(src_select)
+            assignment = core.get_layer_map(policy).assign(
+                src_layers,
+                num_src_layers=self.sender.cfg.attn_layer_count,
+                num_dst_layers=self.receiver.cfg.attn_layer_count,
+                src_scores=(None if src_scores is None
+                            else np.asarray(src_scores)),
+                dst_scores=(None if dst_scores is None
+                            else np.asarray(dst_scores)))
+            kv, states, _ = self.sender.export_kv(context)
+            if states is not None:
+                # SSM state sharing is positional (no mapping policy yet):
+                # only possible when both sides agree on SSM depth
+                from repro.core.protocol import _n_ssm
+                n_ssm = jax.tree.leaves(states)[0].shape[0]
+                if n_ssm != _n_ssm(self.receiver.cfg):
+                    states = None
+            state_select = self._state_selection(kvcfg, states)
+            shared = self._resilient_send(kvcfg, kv, None, states,
+                                          state_select, assignment=assignment,
+                                          sync=sync, rid=rid)
+            return shared, assignment
 
     # ---- multi-sender (§J) ------------------------------------------------
     def attach_sender(self, agent: Agent,

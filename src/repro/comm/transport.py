@@ -65,6 +65,7 @@ from repro.core.protocol import (build_mapped, build_packed, build_shared,
                                  pack_shared, scatter_mapped,
                                  selected_layer_ids)
 from repro.core.types import KVCommConfig, SharedKV
+from repro.utils import spans
 
 _WIRE_DTYPES = {
     "float16": jnp.float16,
@@ -448,9 +449,11 @@ def roundtrip_kv(payload, wire_dtype: str, dtype):
     a codec change cannot diverge their accounting."""
     out, n = {}, 0
     for part in ("k", "v"):
-        wire, nb = encode_wire(payload[part], wire_dtype)
+        with spans.span(spans.WIRE_ENCODE):
+            wire, nb = encode_wire(payload[part], wire_dtype)
         n += nb
-        out[part] = decode_wire(wire, wire_dtype, dtype)
+        with spans.span(spans.WIRE_DECODE):
+            out[part] = decode_wire(wire, wire_dtype, dtype)
     return out, n
 
 
@@ -765,16 +768,18 @@ class Transport(abc.ABC):
             sel_mask = np.asarray(select)
             layer_count = selected_count(select)
         wd = self._paged_wire_dtype(kv)
-        table, novel, novel_bytes = self.store.ingest(
-            payload, layers=layers, select=sel_mask, wire_dtype=wd,
-            pos_mode=kvcfg.pos_mode, src_layers=src_layers)
+        with spans.span(spans.WIRE_ENCODE):
+            table, novel, novel_bytes = self.store.ingest(
+                payload, layers=layers, select=sel_mask, wire_dtype=wd,
+                pos_mode=kvcfg.pos_mode, src_layers=src_layers)
         # ingest pinned the table; release on any failure before the swap
         # so an aborted send cannot leak refcounts into the pool
         try:
             rx_states, state_bytes = self._paged_states(states,
                                                         state_select)
-            shared = self.store.materialize(table, states=rx_states,
-                                            state_select=state_select)
+            with spans.span(spans.WIRE_DECODE):
+                shared = self.store.materialize(table, states=rx_states,
+                                                state_select=state_select)
             if not self.packed:
                 shared = shared.to_dense()
             self._swap_table(table)

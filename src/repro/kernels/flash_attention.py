@@ -164,6 +164,7 @@ def flash_attention_bhsd(
             pltpu.VMEM((blk_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
 
     if collect_mass:

@@ -139,6 +139,7 @@ def _call(q, k, v, kv_len, *, window, blk_k, scale, normalize, interpret):
             jax.ShapeDtypeStruct((B, Hkv, G, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(kv_len.astype(jnp.int32), q, k, v)
     return out, m[..., 0], l[..., 0]
 

@@ -118,8 +118,9 @@ def _call(q, k, v, kv_len, prefix_lens, *, prefix_len, blk_k, scale,
     if pad:
         # tail blocks are masked by rk < seq_kv (seq_kv stays the REAL
         # length), so zero-padding the block axis is purely structural
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        with jax.named_scope("cache_copy"):
+            k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     nk = (Skv + pad) // blk_k
     kernel = functools.partial(
         _ragged_decode_kernel, blk_k=blk_k, seq_kv=Skv,
@@ -150,6 +151,7 @@ def _call(q, k, v, kv_len, prefix_lens, *, prefix_len, blk_k, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
         interpret=interpret,
+        name="ragged_decode",
     )(kv_len.astype(jnp.int32), prefix_lens.astype(jnp.int32), q, k, v)
 
 
@@ -173,14 +175,16 @@ def ragged_decode(q, k, v, kv_len, prefix_lens=None, *, prefix_len: int = 0,
         interpret = jax.default_backend() != "tpu"
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    if D % _LANE:
-        dpad = _LANE - D % _LANE
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, dpad)))
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, dpad)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dpad)))
-    qh = q.reshape(B, Hkv, G, q.shape[-1])
-    kb = jnp.moveaxis(k, 1, 2)   # (B, Hkv, Skv, d)
-    vb = jnp.moveaxis(v, 1, 2)
+    # the kernel's layout of the cache, a copy of every row
+    with jax.named_scope("cache_copy"):
+        if D % _LANE:
+            dpad = _LANE - D % _LANE
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, dpad)))
+            k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, dpad)))
+            v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dpad)))
+        qh = q.reshape(B, Hkv, G, q.shape[-1])
+        kb = jnp.moveaxis(k, 1, 2)   # (B, Hkv, Skv, d)
+        vb = jnp.moveaxis(v, 1, 2)
     kv_len = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     if prefix_lens is None:
         prefix_lens = jnp.full((B,), prefix_len, jnp.int32)

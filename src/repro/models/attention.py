@@ -103,9 +103,10 @@ def self_attention(
     """Returns (out, (new_cache_k, new_cache_v) or (k, v), mass)."""
     B, S, _ = x.shape
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    q = _proj(p, x, "q", cfg, Hq, Dh)
-    k = _proj(p, x, "k", cfg, Hkv, Dh)
-    v = _proj(p, x, "v", cfg, Hkv, Dh)
+    with jax.named_scope("projections"):
+        q = _proj(p, x, "q", cfg, Hq, Dh)
+        k = _proj(p, x, "k", cfg, Hkv, Dh)
+        v = _proj(p, x, "v", cfg, Hkv, Dh)
 
     if mode == "train":
         pos = pos_shift + jnp.arange(S)
@@ -176,12 +177,13 @@ def self_attention(
 
     if ragged:
         # per-row write offsets: each slot appends at its own length
-        start = jnp.minimum(jnp.broadcast_to(cache_len, (B,)), Smax - S)
-        upd = jax.vmap(
-            lambda c, x, s: jax.lax.dynamic_update_slice_in_dim(
-                c, x, s, axis=0))
-        ck = upd(cache_k, k.astype(cache_k.dtype), start)
-        cv = upd(cache_v, v.astype(cache_v.dtype), start)
+        with jax.named_scope("slot_update"):
+            start = jnp.minimum(jnp.broadcast_to(cache_len, (B,)), Smax - S)
+            upd = jax.vmap(
+                lambda c, x, s: jax.lax.dynamic_update_slice_in_dim(
+                    c, x, s, axis=0))
+            ck = upd(cache_k, k.astype(cache_k.dtype), start)
+            cv = upd(cache_v, v.astype(cache_v.dtype), start)
     else:
         ck = jax.lax.dynamic_update_slice_in_dim(
             cache_k, k.astype(cache_k.dtype), cache_len, axis=1)
@@ -204,7 +206,9 @@ def self_attention(
         else:
             pfx = None
         o = ragged_decode(q[:, 0], ck, cv, kvl, pfx, prefix_len=prefix_len)
-        return o.reshape(B, S, -1) @ p["wo"], (ck, cv), None
+        with jax.named_scope("projections"):
+            o = o.reshape(B, S, -1) @ p["wo"]
+        return o, (ck, cv), None
 
     idx = jnp.arange(Smax)
     shift2 = (jnp.broadcast_to(pos_shift, (B,))[:, None]

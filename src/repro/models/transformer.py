@@ -438,7 +438,9 @@ def _attn_layer_body(cfg, spec, mode, prefix_len, collect_mass, enc_out,
         if spec.moe:
             ffn, aux = apply_moe(p["moe"], h, cfg)
         else:
-            ffn, aux = apply_mlp(p["mlp"], h, mt), jnp.zeros((), jnp.float32)
+            with jax.named_scope("mlp"):
+                ffn = apply_mlp(p["mlp"], h, mt)
+            aux = jnp.zeros((), jnp.float32)
         x = x + ffn
         ys["aux"] = aux
         if collect_mass:

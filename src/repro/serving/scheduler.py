@@ -49,6 +49,7 @@ from repro.comm.session import CommSession, _LADDER_ERRORS
 from repro.core.channel import TransferRecord
 from repro.core.types import KVCommConfig, SharedKV
 from repro.models import transformer as tfm
+from repro.utils import spans
 
 
 # ---------------------------------------------------------------------------
@@ -70,6 +71,8 @@ class Completion:
     rid: int
     tokens: np.ndarray           # (max_new,) generated token ids
     ttft_s: float = 0.0          # submit -> first token materialized
+    queue_s: float = 0.0         # submit -> its admission starts
+    admitted_s: float = 0.0      # submit -> its admission is dispatched
     # non-None when the request's KV transfer degraded (fallback transport
     # or text-only baseline) instead of riding the primary path
     degradation: Optional[DegradationEvent] = None
@@ -238,40 +241,44 @@ class Scheduler:
         sqb = min(_bucket(sq_real, cfgd.query_bucket), state["query_max"])
         qry = np.full((1, sqb), self.pad_token, np.int32)
         qry[0, :sq_real] = req.query
-        out = sess.receiver.prefill(
-            qry, core.pad_prefix(shared, scb),
-            max_new=state["budget"],
-            prefix_lens=jnp.full((1,), sc_real, jnp.int32))
+        with spans.span(spans.ADMIT_PREFILL, rid=req.rid):
+            out = sess.receiver.prefill(
+                qry, core.pad_prefix(shared, scb),
+                max_new=state["budget"],
+                prefix_lens=jnp.full((1,), sc_real, jnp.int32))
         tok1 = jnp.argmax(out.logits[:, sq_real - 1, :], axis=-1)  # (1,)
         if req.max_new > 1:
-            store = getattr(sess.transport, "store", None)
-            btab = getattr(sess.transport, "last_table", None)
-            # a degraded/baseline admission must NOT consume the store's
-            # last_table — it belongs to a previous request's (healthy)
-            # exchange, the wrong prefix for this row
-            if self.packed and store is not None and btab is not None \
-                    and degraded is None and not force_baseline:
-                # paged admission: rebuild the prefix from the store's
-                # content-addressed pages (bit-identical to the padded
-                # prefix the row was prefilled with) and let the donated
-                # insert consume the page gather.  Must happen before the
-                # NEXT request's share() swaps/releases the pinned table.
-                prefix_pages = store.gather_prefix(btab, scb)
-                state["table"] = _insert_paged_jit(
-                    state["table"], out.cache, slot,
-                    state["dst_prefix"] + sq_real, prefix_pages,
-                    cfg=sess.cfg, layers=self.layers,
-                    src_prefix=scb, dst_prefix=state["dst_prefix"],
-                    row_max_len=sqb + state["budget"])
-            else:
-                state["table"] = _insert_jit(
-                    state["table"], out.cache, slot,
-                    state["dst_prefix"] + sq_real,
-                    src_prefix=scb, dst_prefix=state["dst_prefix"],
-                    row_max_len=sqb + state["budget"])
-            state["prefix_lens"] = state["prefix_lens"].at[slot].set(sc_real)
-            state["cur_tok"] = state["cur_tok"].at[slot, 0].set(tok1[0])
-            state["active"] = state["active"].at[slot].set(True)
+            with spans.span(spans.ADMIT_INSERT, rid=req.rid):
+                store = getattr(sess.transport, "store", None)
+                btab = getattr(sess.transport, "last_table", None)
+                # a degraded/baseline admission must NOT consume the
+                # store's last_table — it belongs to a previous request's
+                # (healthy) exchange, the wrong prefix for this row
+                if self.packed and store is not None and btab is not None \
+                        and degraded is None and not force_baseline:
+                    # paged admission: rebuild the prefix from the
+                    # store's content-addressed pages (bit-identical to the
+                    # padded prefix the row was prefilled with) and let the
+                    # donated insert consume the page gather.  Must happen
+                    # before the NEXT request's share() swaps/releases the
+                    # pinned table.
+                    prefix_pages = store.gather_prefix(btab, scb)
+                    state["table"] = _insert_paged_jit(
+                        state["table"], out.cache, slot,
+                        state["dst_prefix"] + sq_real, prefix_pages,
+                        cfg=sess.cfg, layers=self.layers,
+                        src_prefix=scb, dst_prefix=state["dst_prefix"],
+                        row_max_len=sqb + state["budget"])
+                else:
+                    state["table"] = _insert_jit(
+                        state["table"], out.cache, slot,
+                        state["dst_prefix"] + sq_real,
+                        src_prefix=scb, dst_prefix=state["dst_prefix"],
+                        row_max_len=sqb + state["budget"])
+                state["prefix_lens"] = \
+                    state["prefix_lens"].at[slot].set(sc_real)
+                state["cur_tok"] = state["cur_tok"].at[slot, 0].set(tok1[0])
+                state["active"] = state["active"].at[slot].set(True)
         return tok1
 
     @property
@@ -283,7 +290,9 @@ class Scheduler:
             ) -> Tuple[List[Completion], Dict[str, float]]:
         """Serve a request stream to completion. Returns the completions
         (rid order) and scheduler metrics (iterations, mean slot occupancy,
-        generated-token count)."""
+        generated-token count).
+
+        Every host phase is a ``kvcomm.sched.*`` span (``utils.spans``)."""
         if not requests:
             return [], {"iterations": 0, "occupancy": 0.0, "tokens": 0}
         sess, cfgd = self.session, self.config
@@ -294,20 +303,21 @@ class Scheduler:
                                  for r in requests), cfgd.prefix_bucket)
         query_max = _bucket(max(int(r.query.shape[0]) for r in requests),
                             cfgd.query_bucket)
-        zshared = self._zero_shared(dst_prefix, cap)
-        table = tfm.init_cache(sess.cfg, cap, query_max + max(budget, 1),
-                               shared=zshared)
-        table["len"] = jnp.full((cap,), dst_prefix, jnp.int32)
-        self.meta = zshared.meta()
-        state = {
-            "table": table,
-            "prefix_lens": jnp.full((cap,), dst_prefix, jnp.int32),
-            "cur_tok": jnp.zeros((cap, 1), jnp.int32),
-            "active": jnp.zeros((cap,), bool),
-            "dst_prefix": dst_prefix,
-            "query_max": query_max,
-            "budget": max(budget, 1),
-        }
+        with spans.span(spans.SCHED_SETUP):
+            zshared = self._zero_shared(dst_prefix, cap)
+            table = tfm.init_cache(sess.cfg, cap, query_max + max(budget, 1),
+                                   shared=zshared)
+            table["len"] = jnp.full((cap,), dst_prefix, jnp.int32)
+            self.meta = zshared.meta()
+            state = {
+                "table": table,
+                "prefix_lens": jnp.full((cap,), dst_prefix, jnp.int32),
+                "cur_tok": jnp.zeros((cap, 1), jnp.int32),
+                "active": jnp.zeros((cap,), bool),
+                "dst_prefix": dst_prefix,
+                "query_max": query_max,
+                "budget": max(budget, 1),
+            }
 
         eos = cfgd.eos_token
 
@@ -321,6 +331,7 @@ class Scheduler:
         first_tok: Dict[int, jnp.ndarray] = {}
         done: Dict[int, _Slot] = {}
         ttft: Dict[int, float] = {}
+        queued: Dict[int, Tuple[float, float]] = {}   # (queue_s, admitted_s)
         fetch_q: deque = deque()      # (iteration_enqueued, array, rids)
         history: List[jnp.ndarray] = []
         occ: List[float] = []
@@ -328,9 +339,10 @@ class Scheduler:
         t0 = time.perf_counter()
         while pending or any(slots):
             # 1) retire finished slots (host-side step counters — no sync)
-            for i, s in enumerate(slots):
-                if s is not None and s.decoded >= s.req.max_new - 1:
-                    _retire(i)
+            with spans.span(spans.SCHED_RETIRE):
+                for i, s in enumerate(slots):
+                    if s is not None and s.decoded >= s.req.max_new - 1:
+                        _retire(i)
             # 2) admit into free slots; the pipeline enqueues behind the
             #    in-flight step — sender prefill overlaps receiver decode
             for i in range(cap):
@@ -338,26 +350,31 @@ class Scheduler:
                     break
                 if slots[i] is None:
                     req = pending.popleft()
-                    try:
-                        tok1 = self._admit(req, state, i)
-                    except _LADDER_ERRORS as e:
-                        # quarantine, don't crash: the failing SENDER's
-                        # admission is downgraded to text-only and the slot
-                        # reused; in-flight rows never notice.  (With a
-                        # session ladder the share degrades internally and
-                        # this path only fires for ladder-less sessions or
-                        # a ladder whose every rung failed.)
-                        ev = DegradationEvent(
-                            stage="baseline",
-                            reason=f"{type(e).__name__}: {e}",
-                            attempts=getattr(e, "attempts", 1), rid=req.rid)
-                        sess.transport.log.append(TransferRecord(
-                            kind="kv", n_bytes=0, layers=0, context_len=0,
-                            wire_dtype="none", attempts=ev.attempts,
-                            degradation=ev))
-                        sess.degradations.append(ev)
-                        tok1 = self._admit(req, state, i,
-                                           force_baseline=True)
+                    with spans.span(spans.SCHED_ADMIT, rid=req.rid):
+                        t_q = time.perf_counter() - t0
+                        try:
+                            tok1 = self._admit(req, state, i)
+                        except _LADDER_ERRORS as e:
+                            # quarantine, don't crash: the failing SENDER's
+                            # admission is downgraded to text-only and the
+                            # slot reused; in-flight rows never notice.
+                            # (With a session ladder the share degrades
+                            # internally and this path only fires for
+                            # ladder-less sessions or a ladder whose every
+                            # rung failed.)
+                            ev = DegradationEvent(
+                                stage="baseline",
+                                reason=f"{type(e).__name__}: {e}",
+                                attempts=getattr(e, "attempts", 1),
+                                rid=req.rid)
+                            sess.transport.log.append(TransferRecord(
+                                kind="kv", n_bytes=0, layers=0,
+                                context_len=0, wire_dtype="none",
+                                attempts=ev.attempts, degradation=ev))
+                            sess.degradations.append(ev)
+                            tok1 = self._admit(req, state, i,
+                                               force_baseline=True)
+                        queued[req.rid] = (t_q, time.perf_counter() - t0)
                     first_tok[req.rid] = tok1
                     fetch_q.append((it, tok1, req.rid))
                     if req.max_new > 1:
@@ -368,17 +385,18 @@ class Scheduler:
                                               start_hist=len(history))
             # 3) one ragged iteration over the whole table
             if any(slots):
-                ntok, _, state["table"] = sess.receiver.ragged_step(
-                    state["cur_tok"], state["table"], self.meta,
-                    state["prefix_lens"], state["active"],
-                    backend=cfgd.decode_backend)
-                state["cur_tok"] = ntok[:, None]
-                history.append(ntok)
-                live = sum(s is not None for s in slots)
-                occ.append(live / cap)
-                for s in slots:
-                    if s is not None:
-                        s.decoded += 1
+                with spans.span(spans.SCHED_STEP):
+                    ntok, _, state["table"] = sess.receiver.ragged_step(
+                        state["cur_tok"], state["table"], self.meta,
+                        state["prefix_lens"], state["active"],
+                        backend=cfgd.decode_backend)
+                    state["cur_tok"] = ntok[:, None]
+                    history.append(ntok)
+                    live = sum(s is not None for s in slots)
+                    occ.append(live / cap)
+                    for s in slots:
+                        if s is not None:
+                            s.decoded += 1
             # 4) double buffering: materialize LAST iteration's results
             #    while this one executes; stamps TTFT one step late at most.
             #    The same lagged reads drive EOS-based early exit: a slot
@@ -386,67 +404,74 @@ class Scheduler:
             #    row is readmitted next iteration instead of decoding out
             #    the full budget (detection lags one step — the wasted
             #    tokens are truncated from the completion below).
-            while fetch_q and fetch_q[0][0] < it:
-                _, arr, rid = fetch_q.popleft()
-                tok0 = int(np.asarray(arr)[0])
-                ttft.setdefault(rid, time.perf_counter() - t0)
-                if eos is not None and tok0 == eos:
-                    for i, s in enumerate(slots):
-                        if s is not None and s.req.rid == rid:
-                            _retire(i)
-            if len(history) >= 2:
-                h = np.asarray(history[-2])
-                if eos is not None:
-                    row = len(history) - 2
-                    for i, s in enumerate(slots):
-                        if s is not None and row >= s.start_hist \
-                                and h[s.col] == eos:
-                            _retire(i)
+            with spans.span(spans.SCHED_READ):
+                while fetch_q and fetch_q[0][0] < it:
+                    _, arr, rid = fetch_q.popleft()
+                    tok0 = int(np.asarray(arr)[0])
+                    ttft.setdefault(rid, time.perf_counter() - t0)
+                    if eos is not None and tok0 == eos:
+                        for i, s in enumerate(slots):
+                            if s is not None and s.req.rid == rid:
+                                _retire(i)
+                if len(history) >= 2:
+                    h = np.asarray(history[-2])
+                    if eos is not None:
+                        row = len(history) - 2
+                        for i, s in enumerate(slots):
+                            if s is not None and row >= s.start_hist \
+                                    and h[s.col] == eos:
+                                _retire(i)
             # settle drained transfer stamps without blocking, so the
             # deferred log (which pins receiver views on device) stays
             # bounded by in-flight transfers, not stream length
-            sess.transport.poll_latency()
+            with spans.span(spans.SCHED_POLL):
+                sess.transport.poll_latency()
             it += 1
 
-        # drain: one host sync for everything still in flight
-        hist = (np.asarray(jnp.stack(history)) if history
-                else np.zeros((0, cap), np.int32))
-        now = time.perf_counter() - t0
-        for _, arr, rid in fetch_q:
-            np.asarray(arr)
-            ttft.setdefault(rid, now)
-        sess.transport.flush_latency()
+        with spans.span(spans.SCHED_DRAIN):
+            # drain: one host sync for everything still in flight
+            hist = (np.asarray(jnp.stack(history)) if history
+                    else np.zeros((0, cap), np.int32))
+            now = time.perf_counter() - t0
+            for _, arr, rid in fetch_q:
+                np.asarray(arr)
+                ttft.setdefault(rid, now)
+            sess.transport.flush_latency()
 
-        # per-request degradation events from this run (last per rid wins)
-        dmap: Dict[int, DegradationEvent] = {
-            ev.rid: ev for ev in sess.degradations[n_deg0:]
-            if ev.rid is not None}
-        completions = []
-        for rid in sorted(done):
-            s = done[rid]
-            toks = [int(np.asarray(first_tok[rid])[0])]
-            if s.req.max_new > 1:
-                # the request's decode tokens live in its own slot column,
-                # at the s.decoded history rows it was live for (its full
-                # budget unless EOS retired it early — later rows of that
-                # column may already belong to a readmitted request)
-                toks.extend(hist[s.start_hist:
-                                 s.start_hist + s.decoded, s.col]
-                            .tolist())
-            if eos is not None and eos in toks:
-                # EOS detection lags the lagged host read by a step or
-                # two; everything decoded past the EOS is dead weight
-                toks = toks[:toks.index(eos) + 1]
-            completions.append(Completion(
-                rid=rid, tokens=np.asarray(toks, np.int32),
-                ttft_s=ttft.get(rid, now), degradation=dmap.get(rid)))
-        return completions, {
-            "iterations": it,
-            "occupancy": float(np.mean(occ)) if occ else 0.0,
-            # tokens actually DELIVERED (EOS truncation included) — the
-            # honest numerator for any tokens/s derived from these stats
-            "tokens": int(sum(len(c.tokens) for c in completions)),
-        }
+            # per-request degradation events from this run (last per rid
+            # wins)
+            dmap: Dict[int, DegradationEvent] = {
+                ev.rid: ev for ev in sess.degradations[n_deg0:]
+                if ev.rid is not None}
+            completions = []
+            for rid in sorted(done):
+                s = done[rid]
+                toks = [int(np.asarray(first_tok[rid])[0])]
+                if s.req.max_new > 1:
+                    # the request's decode tokens live in its own slot
+                    # column, at the s.decoded history rows it was live for
+                    # (its full budget unless EOS retired it early — later
+                    # rows of that column may already belong to a
+                    # readmitted request)
+                    toks.extend(hist[s.start_hist:
+                                     s.start_hist + s.decoded, s.col]
+                                .tolist())
+                if eos is not None and eos in toks:
+                    # EOS detection lags the lagged host read by a step or
+                    # two; everything decoded past the EOS is dead weight
+                    toks = toks[:toks.index(eos) + 1]
+                q_s, adm_s = queued[rid]
+                completions.append(Completion(
+                    rid=rid, tokens=np.asarray(toks, np.int32),
+                    ttft_s=ttft.get(rid, now), queue_s=q_s,
+                    admitted_s=adm_s, degradation=dmap.get(rid)))
+            return completions, {
+                "iterations": it,
+                "occupancy": float(np.mean(occ)) if occ else 0.0,
+                # tokens actually DELIVERED (EOS truncation included) — the
+                # honest numerator for any tokens/s derived from these stats
+                "tokens": int(sum(len(c.tokens) for c in completions)),
+            }
 
 
 # ---------------------------------------------------------------------------
@@ -467,8 +492,10 @@ def serve_serial(session: CommSession, requests: Sequence[Request],
     completions = []
     t0 = time.perf_counter()
     for req in sorted(requests, key=lambda r: r.rid):
+        queue_s = time.perf_counter() - t0
         shared, _ = session.share(req.context[None, :], kvcfg,
                                   key=calib_key, sync=True, rid=req.rid)
+        admitted_s = time.perf_counter() - t0
         degraded = session.last_degradation
         toks, ttft = [], 0.0
         for step_tok in session.stream(req.query[None, :], shared,
@@ -481,7 +508,7 @@ def serve_serial(session: CommSession, requests: Sequence[Request],
                 break
         completions.append(Completion(
             rid=req.rid, tokens=np.asarray(toks, np.int32), ttft_s=ttft,
-            degradation=degraded))
+            queue_s=queue_s, admitted_s=admitted_s, degradation=degraded))
     return completions, {
         "iterations": sum(len(c.tokens) for c in completions),
         # one request at a time: the single implicit slot is always busy

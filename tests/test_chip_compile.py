@@ -9,6 +9,8 @@ whether the chip would accept the program and how much memory it needs.
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and a test worker that loads it keeps it.
 """
+import re
+
 import pytest
 
 import jax
@@ -124,7 +126,16 @@ def test_ragged_decode_step_compiles(put, monkeypatch, backend):
         put(params), cfg, put(_sds((B, 1), jnp.int32)), put(table),
         put(meta), put(_sds((B,), jnp.int32)), put(_sds((B,), jnp.bool_)),
         backend=backend).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (backend == "pallas")
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == (backend == "pallas")
+    # the names a trace reduction maps device ops by: the kernel's
+    # instruction, and the step's named scopes in each op's metadata
+    scopes = ["projections", "slot_update", "mlp"]
+    if backend == "pallas":
+        assert re.search(r"%ragged_decode\.\d+ = .*tpu_custom_call", hlo)
+        scopes.append("cache_copy")
+    for scope in scopes:
+        assert f"/{scope}/" in hlo, scope
     ma = compiled.memory_analysis()
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
