@@ -16,7 +16,8 @@ Phases (any failure exits non-zero):
 1. device check — ``jax.devices()[0].platform`` must be ``tpu``; there is no
    CPU fallback.
 2. kernel check — the compiled ``ragged_decode`` kernel, prefix-free and
-   two-segment, against ``kernels.ref.ragged_decode_reference``.
+   two-segment, reading one layer of a poisoned stack in place, against
+   ``kernels.ref.ragged_decode_reference``.
 3. in-process scheduler — calibrate, then 8 requests with contexts tiled to
    512-1,024 tokens, ``max_new`` 8, under the ``reference`` and the
    ``pallas`` decode backend: finite logits, first-step logits of the two
@@ -106,18 +107,23 @@ def shapes_of(tree):
 # ---------------------------------------------------------------------------
 def kernel_phase(cfg, *, batch: int, seq: int, prefix_len: int) -> None:
     from repro.kernels import ref
-    from repro.kernels.ragged_decode import ragged_decode
+    from repro.kernels.ragged_decode import ragged_decode_stack
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     ks = jax.random.split(jax.random.PRNGKey(7), 5)
     q = jax.random.normal(ks[0], (batch, Hq, D), jnp.bfloat16)
     k = jax.random.normal(ks[1], (batch, seq, Hkv, D), jnp.bfloat16)
     v = jax.random.normal(ks[2], (batch, seq, Hkv, D), jnp.bfloat16)
+    # the layer is read in place from a 3-layer stack whose other layers
+    # are poison: a read of the wrong layer, or of a NaN row past the
+    # stack's end, shows in the comparison
+    junk = jnp.full((3, batch, seq, Hkv, D), jnp.nan, jnp.bfloat16)
+    kst, vst = junk.at[1].set(k), junk.at[1].set(v)
     for pfx_len in (0, prefix_len):
         kv_len = jax.random.randint(ks[3], (batch,), pfx_len + 1, seq + 1)
         pfx = jax.random.randint(ks[4], (batch,), 0, pfx_len + 1)
         kv_len, pfx = kv_len.at[-1].set(0), pfx.at[-1].set(0)  # a dead row
-        out = jax.jit(lambda *a: ragged_decode(*a, prefix_len=pfx_len))(
-            q, k, v, kv_len, pfx)
+        out = jax.jit(lambda *a: ragged_decode_stack(
+            *a, prefix_len=pfx_len))(q, kst, vst, 1, kv_len, pfx)
         # the oracle in float32 on the same bf16 inputs: the kernel also
         # accumulates in float32, so what differs is its bf16 output
         # rounding (2^-8 relative) and p.v at Mosaic's matmul precision

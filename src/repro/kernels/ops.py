@@ -18,7 +18,7 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.flash_decode import flash_decode, flash_decode_partials
 # re-export: the serving hot loop's two-segment packed-prefix decode
-# (handles its own D/blk padding — see kernels/ragged_decode.py)
+# (reads the cache in its own layout — see kernels/ragged_decode.py)
 from repro.kernels.ragged_decode import ragged_decode  # noqa: F401
 from repro.kernels.rwkv_scan import wkv6
 

@@ -87,8 +87,10 @@ def self_attention(
                                             # (the BUFFER size; per-row real
                                             # lengths ride in prefix_lens)
     ctx_valid: Optional[jnp.ndarray] = None,  # scalar bool: layer selected?
-    cache_k: Optional[jnp.ndarray] = None,  # (B, Smax, Hkv, Dh)
-    cache_v: Optional[jnp.ndarray] = None,
+    cache_k: Optional[jnp.ndarray] = None,  # (B, Smax, Hkv, Dh), or the
+    cache_v: Optional[jnp.ndarray] = None,  # run's (m, ...) stack with
+    cache_layer=None,                       # this (traced) layer index:
+                                            # written and read in place
     cache_len=None,                         # scalar or (B,): valid entries
                                             # (>= prefix; per-row = ragged
                                             # continuous-batching rows)
@@ -100,7 +102,8 @@ def self_attention(
                                             # "reference" (masked dense) or
                                             # "pallas" (fused ragged kernel)
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray], Optional[jnp.ndarray]]:
-    """Returns (out, (new_cache_k, new_cache_v) or (k, v), mass)."""
+    """Returns (out, (new_cache_k, new_cache_v) or (k, v), mass); with
+    ``cache_layer`` the new caches are the whole updated stacks."""
     B, S, _ = x.shape
     Hq, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     with jax.named_scope("projections"):
@@ -137,9 +140,10 @@ def self_attention(
         q = rope(q, pb, cfg.rope_theta)
         k = rope(k, pb, cfg.rope_theta)
 
-    Smax = cache_k.shape[1]
+    stacked = cache_layer is not None
+    Smax = cache_k.shape[-3]
     ring = (cfg.ring_cache and window is not None and Smax == window
-            and prefix_len == 0 and not ragged)
+            and prefix_len == 0 and not ragged and not stacked)
     if ring:
         # vLLM-style ring buffer: slot for absolute index i is i % W.
         W = Smax
@@ -175,20 +179,9 @@ def self_attention(
             kv_valid=valid, causal=causal, window=window, mass_mask=None)
         return out.reshape(B, S, -1) @ p["wo"], (ck, cv), mass
 
-    if ragged:
-        # per-row write offsets: each slot appends at its own length
-        with jax.named_scope("slot_update"):
-            start = jnp.minimum(jnp.broadcast_to(cache_len, (B,)), Smax - S)
-            upd = jax.vmap(
-                lambda c, x, s: jax.lax.dynamic_update_slice_in_dim(
-                    c, x, s, axis=0))
-            ck = upd(cache_k, k.astype(cache_k.dtype), start)
-            cv = upd(cache_v, v.astype(cache_v.dtype), start)
-    else:
-        ck = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), cache_len, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), cache_len, axis=1)
+    with jax.named_scope("slot_update"):
+        ck = _cache_write(cache_k, k, cache_len, cache_layer, ragged)
+        cv = _cache_write(cache_v, v, cache_len, cache_layer, ragged)
 
     if backend == "pallas" and S == 1 and window is None and not collect_mass:
         # Fused ragged decode: one two-segment kernel per layer, no dense
@@ -196,7 +189,8 @@ def self_attention(
         # (RoPE applied above), so only the validity geometry ships:
         # kv_len = total valid entries, pfx = real prefix entries (0 when
         # ctx_valid masks the prefix at an unselected layer).
-        from repro.kernels.ragged_decode import ragged_decode
+        from repro.core.protocol import TRACE_COUNTS
+        from repro.kernels.ragged_decode import ragged_decode_stack
         kvl = (jnp.broadcast_to(cache_len, (B,)) + S).astype(jnp.int32)
         if prefix_len:
             pfx = (prefix_lens if prefix_lens is not None
@@ -205,7 +199,14 @@ def self_attention(
                 pfx = jnp.where(ctx_valid, pfx, 0)
         else:
             pfx = None
-        o = ragged_decode(q[:, 0], ck, cv, kvl, pfx, prefix_len=prefix_len)
+        # trace-time record of how the cache reached the kernel: the run's
+        # stack in place, or a layer the scan sliced out of it
+        TRACE_COUNTS["ragged_decode[in_place]" if stacked
+                     else "ragged_decode[copy]"] += 1
+        sk, sv, layer = (ck, cv, cache_layer) if stacked \
+            else (ck[None], cv[None], 0)
+        o = ragged_decode_stack(q[:, 0], sk, sv, layer, kvl, pfx,
+                                prefix_len=prefix_len)
         with jax.named_scope("projections"):
             o = o.reshape(B, S, -1) @ p["wo"]
         return o, (ck, cv), None
@@ -239,9 +240,51 @@ def self_attention(
     # the shifted query position or masked by ctx_valid), so the causal
     # comparison over the whole buffer is dead work in the per-token step
     out, mass = _core(cfg)(
-        q, ck, cv, q_pos=q_pos, kv_pos=kv_pos, kv_valid=valid,
-        causal=causal and S > 1, window=window, mass_mask=mass_mask)
+        q, layer_of(ck, cache_layer), layer_of(cv, cache_layer), q_pos=q_pos,
+        kv_pos=kv_pos, kv_valid=valid, causal=causal and S > 1,
+        window=window, mass_mask=mass_mask)
     return out.reshape(B, S, -1) @ p["wo"], (ck, cv), mass
+
+
+def layer_of(cache, layer):
+    """One layer's view of a cache: the layer of a stack, or the cache
+    itself where there is no stack (``layer is None``)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+
+
+def _cache_write(cache, x, cache_len, layer, ragged):
+    """Write the S new rows ``x`` (B, S, Hkv, Dh) at each row's length.
+
+    Ragged rows append at their own lengths (clamped so that a dead slot
+    rewrites its own masked position rather than walking off the buffer).
+    With a ``layer``, ``cache`` is the whole stack and only the new rows
+    are written, in place; the rest of the stack is never copied."""
+    B, S = x.shape[:2]
+    x = x.astype(cache.dtype)
+    if not ragged:
+        if layer is None:
+            return jax.lax.dynamic_update_slice_in_dim(cache, x, cache_len,
+                                                       axis=1)
+        return jax.lax.dynamic_update_slice(
+            cache, x[None], (layer, 0, cache_len, 0, 0))
+    start = jnp.minimum(jnp.broadcast_to(cache_len, (B,)),
+                        cache.shape[-3] - S)
+    if layer is None:
+        return jax.vmap(lambda c, r, s: jax.lax.dynamic_update_slice_in_dim(
+            c, r, s, axis=0))(cache, x, start)
+    if S == 1:
+        # decode: one row per slot, all of them in one scatter
+        return cache.at[layer, jnp.arange(B), start].set(
+            x[:, 0], indices_are_sorted=True, unique_indices=True,
+            mode="promise_in_bounds")
+    # prefill: a row's S new entries are contiguous, one update each (a
+    # scatter would write them one entry at a time)
+    for b in range(B):
+        cache = jax.lax.dynamic_update_slice(
+            cache, x[b][None, None], (layer, b, start[b], 0, 0))
+    return cache
 
 
 def init_cross_attn(key, cfg):

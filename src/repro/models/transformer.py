@@ -394,6 +394,7 @@ def _attn_layer_body(cfg, spec, mode, prefix_len, collect_mass, enc_out,
     def body(x, per):
         p = per["params"]
         cache = per.get("cache")
+        layer = (cache or {}).get("layer")   # set where stacks are carried
         cap = x[:, -1, :] if capture_hidden else None
         if inject_mode is not None:
             # AC baseline (Ramesh & Li 2025): merge the sender's last-token
@@ -413,6 +414,7 @@ def _attn_layer_body(cfg, spec, mode, prefix_len, collect_mass, enc_out,
             ctx_valid=(cache or {}).get("ctx_valid"),
             cache_k=(cache or {}).get("k"),
             cache_v=(cache or {}).get("v"),
+            cache_layer=layer,
             cache_len=per.get("cache_len"),
             prefix_lens=per.get("prefix_lens"),
             collect_mass=collect_mass,
@@ -428,9 +430,12 @@ def _attn_layer_body(cfg, spec, mode, prefix_len, collect_mass, enc_out,
             if mode == "cached":
                 if enc_out is not None:   # prefill: build cross KV
                     xk, xv = attn_mod.cross_kv(p["xattn"], cfg, enc_out)
+                    ys["xk"] = _put_layer(cache["xk"], xk, layer)
+                    ys["xv"] = _put_layer(cache["xv"], xv, layer)
                 else:                     # decode: reuse cached cross KV
-                    xk, xv = cache["xk"], cache["xv"]
-                ys["xk"], ys["xv"] = xk, xv
+                    ys["xk"], ys["xv"] = cache["xk"], cache["xv"]
+                    xk = attn_mod.layer_of(cache["xk"], layer)
+                    xv = attn_mod.layer_of(cache["xv"], layer)
             else:
                 xk, xv = attn_mod.cross_kv(p["xattn"], cfg, enc_out)
             x = x + attn_mod.cross_attention(p["xattn"], cfg, h, xk, xv)
@@ -451,6 +456,15 @@ def _attn_layer_body(cfg, spec, mode, prefix_len, collect_mass, enc_out,
         return x, ys
 
     return body
+
+
+def _put_layer(cache, x, layer):
+    """``x`` as one layer's new cache: the layer of a stack, written in
+    place, or ``x`` itself where there is no stack (``layer is None``)."""
+    if layer is None:
+        return x
+    return jax.lax.dynamic_update_index_in_dim(cache, x.astype(cache.dtype),
+                                               layer, 0)
 
 
 def _ssm_layer_body(cfg, spec, mode):
@@ -478,6 +492,28 @@ def _ssm_layer_body(cfg, spec, mode):
     return body
 
 
+def _carried_cache_body(body):
+    """Wrap a layer body so the run's cache stacks ride in the scan carry.
+
+    The layer finds the whole stacks and its own index (``per["slot"]``)
+    in its cache, writes its new rows into them in place and hands them on
+    to the next layer: no layer's cache is sliced out of a stack through
+    the scan's inputs or stacked anew through its outputs.  ``ctx_valid``
+    is read, never written."""
+    def step(carry, per):
+        x, stacks = carry
+        per = dict(per)
+        i = per.pop("slot")
+        per["cache"] = dict(stacks, layer=i,
+                            ctx_valid=stacks["ctx_valid"][i])
+        x, ys = body(x, per)
+        ys.pop("ctx_valid")
+        new = {kk: stacks[kk] if kk == "ctx_valid" else ys.pop(kk)
+               for kk in stacks}
+        return (hints.shard_activations(x), new), ys
+    return step
+
+
 def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
                            attn_i, cache_len, prefix_len, collect_mass,
                            capture_hidden, enc_out, prefix_lens=None,
@@ -491,23 +527,18 @@ def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
     parameters from the unpartitioned stack by index. Prefix attention
     FLOPs therefore scale with the number of selected layers, not the run
     length, and the unselected buffers never hold (or mask) prefix entries.
+    Each segment carries its whole stack and addresses its layers as
+    ``s0 + i`` in it, so the step writes only the new rows and the donated
+    cache is updated where it lives.
     """
     sel, unsel, segments = _run_partition(attn_i, spec.count, shared.layers)
-    stack_len = {"sel": len(sel), "unsel": len(unsel)}
-    cache_keys = ["k", "v", "ctx_valid"]
-    if spec.cross_attn:
-        cache_keys += ["xk", "xv"]
-    new_sub = {"sel": [], "unsel": []}
+    stacks = {name: dict(run_cache[name]) for name in ("sel", "unsel")}
     masses, hiddens = [], []
     aux = jnp.zeros((), jnp.float32)
     zero_unsel = shared.pos_mode == "zero_unselected"
     j0 = 0   # the segment's first layer within the run
     for is_sel, s0, ln in segments:
         name = "sel" if is_sel else "unsel"
-        whole = ln == stack_len[name]
-        sub_cache = {kk: (run_cache[name][kk] if whole
-                          else run_cache[name][kk][s0:s0 + ln])
-                     for kk in cache_keys}
         pfx = prefix_len if is_sel else 0
         clen = cache_len if is_sel else cache_len - prefix_len
         if prefix_lens is not None:
@@ -521,35 +552,27 @@ def _apply_packed_attn_run(run_p, cfg, spec, x, run_cache, *, shared,
             shift = 0 if (zero_unsel and not is_sel) else prefix_len
             shift_arr = jnp.full((ln,), shift, jnp.int32)
         per = {"layer": jnp.arange(j0, j0 + ln),
+               "slot": jnp.arange(s0, s0 + ln),
                "pos_shift": shift_arr,
-               "cache": sub_cache,
                "cache_len": jnp.broadcast_to(clen,
                                              (ln,) + jnp.shape(clen))}
         if prefix_lens is not None and is_sel:
             per["prefix_lens"] = jnp.broadcast_to(
                 prefix_lens[None], (ln,) + prefix_lens.shape)
-        body = _indexed_layer_body(
+        body = _carried_cache_body(_indexed_layer_body(
             _attn_layer_body(cfg, spec, "cached", pfx, collect_mass,
                              enc_out, capture_hidden=capture_hidden,
-                             backend=backend), run_p)
-        x, ys = _run_scan(body, x, per, remat=False, unroll=cfg.scan_unroll)
+                             backend=backend), run_p))
+        (x, stacks[name]), ys = jax.lax.scan(
+            body, (x, stacks[name]), per,
+            unroll=True if cfg.scan_unroll else 1)
         j0 += ln
         aux = aux + jnp.sum(ys["aux"])
         if collect_mass:
             masses.append(ys["mass"])
         if capture_hidden:
             hiddens.append(ys["h_last"])
-        new_sub[name].append({kk: ys[kk] for kk in cache_keys})
-    entry = {}
-    for name in ("sel", "unsel"):
-        if len(new_sub[name]) > 1:
-            entry[name] = jax.tree.map(
-                lambda *xs: jnp.concatenate(xs, axis=0), *new_sub[name])
-        elif new_sub[name]:
-            entry[name] = new_sub[name][0]
-        else:
-            entry[name] = run_cache[name]   # empty stack: passes through
-    return x, entry, aux, masses, hiddens
+    return x, stacks, aux, masses, hiddens
 
 
 def _run_scan(body, x, per_layer, *, remat: bool, unroll: bool = False):

@@ -9,6 +9,7 @@ whether the chip would accept the program and how much memory it needs.
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and a test worker that loads it keeps it.
 """
+import math
 import re
 
 import pytest
@@ -98,12 +99,37 @@ def test_flash_attention_compiles(put):
     assert "tpu_custom_call" in hlo
 
 
+_COPY_OPS = {"copy", "transpose", "pad", "slice", "dynamic-slice",
+             "concatenate"}
+_INSTR = re.compile(r"%(\S+) = \w+\[([0-9,]*)\]\S* ([\w-]+)\(")
+
+
+def _cache_copies(hlo, stacks, batch):
+    """The compiled module's copies, transposes, pads, slices and joins
+    (alone, or as the kinds a fusion is named by) of an array shaped like
+    the cache — ``batch`` rows of head-dim vectors — with at least one
+    layer's worth of a cache stack's entries."""
+    one_layer = min(math.prod(a.shape[1:]) for a in stacks)
+    head_dim = stacks[0].shape[-1]
+    found = []
+    for name, dims, op in _INSTR.findall(hlo):
+        dims = [int(d) for d in dims.split(",") if d]
+        kinds = {op} | (set(re.split(r"[_.]", name)) if op == "fusion"
+                        else set())
+        if (kinds & _COPY_OPS and dims and dims[-1] == head_dim
+                and batch in dims[:-1] and math.prod(dims) >= one_layer):
+            found.append((name, op, dims))
+    return found
+
+
 @pytest.mark.parametrize("backend", ["pallas", "reference"])
 def test_ragged_decode_step_compiles(put, monkeypatch, backend):
     """The scheduler's donated ragged step over the packed slot table at
     ratio 0.5: it fits one chip, and the pallas backend really carries the
     Mosaic kernel (the step picks interpret mode from the backend, which in
-    this process is the CPU — so tell it the chip is there)."""
+    this process is the CPU — so tell it the chip is there), reads the
+    table in place — no copy, slice, pad, transpose or join of a layer's
+    K or V — and hands every cache buffer back as the donated one."""
     cfg = get_config(ARCH)
     kvcfg = KVCommConfig(ratio=0.5, selector="prior_only")
     select = core.make_selection(cfg, kvcfg)
@@ -130,12 +156,19 @@ def test_ragged_decode_step_compiles(put, monkeypatch, backend):
     assert ("tpu_custom_call" in hlo) == (backend == "pallas")
     # the names a trace reduction maps device ops by: the kernel's
     # instruction, and the step's named scopes in each op's metadata
-    scopes = ["projections", "slot_update", "mlp"]
+    for scope in ("projections", "slot_update", "mlp"):
+        assert f"/{scope}/" in hlo, scope
     if backend == "pallas":
         assert re.search(r"%ragged_decode\.\d+ = .*tpu_custom_call", hlo)
-        scopes.append("cache_copy")
-    for scope in scopes:
-        assert f"/{scope}/" in hlo, scope
+        # the slot table is read and written where it lives
+        stacks = [a for a in jax.tree.leaves(table) if a.ndim == 5]
+        copies = _cache_copies(hlo, stacks, B)
+        assert not copies, copies
+        aliased = set(re.findall(r"\(([0-9]+), \{\}, may-alias\)", hlo))
+        cache_params = re.findall(r"%cache\S* = \S+ parameter\(([0-9]+)\)",
+                                  hlo)
+        assert len(cache_params) == len(jax.tree.leaves(table))
+        assert set(cache_params) <= aliased, (cache_params, aliased)
     ma = compiled.memory_analysis()
     need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)

@@ -17,12 +17,14 @@ from repro.core.protocol import DECODE_BACKENDS, TRACE_COUNTS
 from repro.core.types import KVCommConfig
 from repro.data.synthetic import SyntheticTask, TaskConfig
 from repro.kernels import ref
-from repro.kernels.ragged_decode import ragged_decode
+from repro.kernels.ragged_decode import (ragged_decode,
+                                         ragged_decode_stack)
 from repro.models import transformer as tfm
 from repro.serving.scheduler import (Scheduler, SchedulerConfig,
                                      make_requests, serve_serial)
 
 KEY = jax.random.PRNGKey(3)
+F32, BF16 = jnp.float32, jnp.bfloat16
 KVCFG = KVCommConfig(ratio=0.5, selector="prior_only")
 
 
@@ -36,28 +38,53 @@ def _rand(key, shape, dtype=jnp.float32):
 class TestRaggedDecodeKernel:
     """ragged_decode against the pure-jnp two-segment oracle."""
 
-    @pytest.mark.parametrize("B,S,prefix_len,Hq,Hkv,D,blk_k", [
-        (2, 24, 8, 4, 2, 16, 8),     # GQA, aligned blocks
-        (2, 24, 8, 4, 2, 16, 7),     # odd blk_k, non-multiple
-        (3, 5, 0, 2, 2, 32, 256),    # no prefix segment, S < blk_k
-        (2, 40, 16, 8, 2, 64, 16),   # wide GQA, big prefix
-        (1, 17, 4, 6, 3, 16, 4),     # ragged everything
+    @pytest.mark.parametrize("B,S,prefix_len,Hq,Hkv,D,blk_k,stack,dtype", [
+        (2, 24, 8, 4, 2, 16, 8, None, F32),     # GQA, aligned blocks
+        (2, 24, 8, 4, 2, 16, 7, None, F32),     # odd blk_k, non-multiple
+        (3, 5, 0, 2, 2, 32, 256, None, F32),    # no prefix segment, S < blk_k
+        (2, 40, 16, 8, 2, 64, 16, None, F32),   # wide GQA, big prefix
+        (1, 17, 4, 6, 3, 16, 4, None, F32),     # ragged everything
+        # the in-place entry, layer `layer` of an m-layer stack: S off the
+        # block grid (the tail block reads past the stack, which the
+        # interpreter fills with NaN), G = 1 and G > 1, d < 128 and 128,
+        # and bf16 stacks, whose heads the kernel reads in pairs
+        (2, 24, 8, 4, 4, 16, 7, (3, 1), F32),   # G = 1, d < 128
+        (2, 40, 0, 8, 2, 128, 16, (2, 0), F32),  # G = 4, no prefix
+        (3, 37, 16, 6, 2, 128, 8, (4, 3), BF16),  # G = 3, bf16 pairs
+        (2, 45, 8, 4, 4, 128, 16, (3, 2), BF16),  # G = 1, bf16 pairs
+        (2, 21, 0, 4, 4, 16, 8, (2, 1), BF16),  # prefix-free, d < 128
     ])
-    def test_matches_oracle(self, B, S, prefix_len, Hq, Hkv, D, blk_k):
+    def test_matches_oracle(self, B, S, prefix_len, Hq, Hkv, D, blk_k,
+                            stack, dtype):
         ks = jax.random.split(KEY, 5)
-        q = _rand(ks[0], (B, Hq, D))
-        k = _rand(ks[1], (B, S, Hkv, D))
-        v = _rand(ks[2], (B, S, Hkv, D))
+        q = _rand(ks[0], (B, Hq, D), dtype)
+        k = _rand(ks[1], (B, S, Hkv, D), dtype)
+        v = _rand(ks[2], (B, S, Hkv, D), dtype)
         kv_len = jax.random.randint(ks[3], (B,), prefix_len + 1, S + 1)
         pfx = (jax.random.randint(ks[4], (B,), 0, prefix_len + 1)
                if prefix_len else None)
         out = ragged_decode(q, k, v, kv_len, pfx, prefix_len=prefix_len,
                             blk_k=blk_k)
-        rout = ref.ragged_decode_reference(q, k, v, kv_len=kv_len,
+        if stack is not None:
+            # the stack's other layers are poison (NaN, 1e6 alternately):
+            # reading the layer in place equals reading it alone, exactly
+            m, layer = stack
+            junk = jnp.where(jnp.arange(m) % 2, 1e6, jnp.nan)
+            junk = jnp.broadcast_to(junk[:, None, None, None, None],
+                                    (m,) + k.shape).astype(dtype)
+            got = ragged_decode_stack(
+                q, junk.at[layer].set(k), junk.at[layer].set(v), layer,
+                kv_len, pfx, prefix_len=prefix_len, blk_k=blk_k)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(out))
+        f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+        rout = ref.ragged_decode_reference(*f32, kv_len=kv_len,
                                            prefix_lens=pfx,
                                            prefix_len=prefix_len)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(rout),
-                                   atol=2e-5, rtol=2e-5)
+        # bf16: the kernel widens its operands exactly and rounds only its
+        # output, by at most half a bf16 ulp (2^-8 relative)
+        tol = 2e-5 if dtype == F32 else 4e-3
+        np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                                   np.asarray(rout), atol=tol, rtol=tol)
 
     @pytest.mark.parametrize("seed", range(4))
     def test_segment_mask_equals_bool_predicate(self, seed):
@@ -289,6 +316,12 @@ class TestBackendTraceCounts:
         d_pal = after.get("ragged_decode_step[pallas]", 0) \
             - base.get("ragged_decode_step[pallas]", 0)
         assert d_pal == 1, f"expected one pallas step compile, saw {d_pal}"
+        # the packed step hands the kernel its stacks in place, never a
+        # layer sliced out of them
+        assert after.get("ragged_decode[in_place]", 0) \
+            > base.get("ragged_decode[in_place]", 0)
+        assert after.get("ragged_decode[copy]", 0) \
+            == base.get("ragged_decode[copy]", 0)
         # the legacy aggregate counter tracks the same trace
         assert after.get("ragged_decode_step", 0) \
             - base.get("ragged_decode_step", 0) == 1
