@@ -2,8 +2,10 @@
 
 A cell (``workloads/<cell>.json``) names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<traffic>.json``)
-and holds the serving parameters of the cell.  Adding a cell, a
-configuration or a traffic mix adds files here and edits none.
+and holds the serving parameters of the cell.  A configuration names its
+model family (``families/<family>.py``; ``families/__init__.py`` says what
+a family gives).  Adding a cell, a configuration, a traffic mix or a model
+family adds files here and edits none.
 
 One generator turns a traffic file into closed waves of requests.  A file
 gives each length (``prefix``, ``query``, ``max_new``) as a distribution:
@@ -27,6 +29,7 @@ reordered per seed, would compile inside the window.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import math
 import os
@@ -55,20 +58,21 @@ def load_cell(name: str) -> dict:
     return cell
 
 
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file: the published
-    keys as run, plus the program options the file names."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=conf["name"], arch_type="dense", source=conf["source"],
-        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"], d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"], rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=conf["tie_word_embeddings"],
-        dtype=conf["torch_dtype"], remat=False, **conf.get("program", {}))
+def family(conf: dict):
+    """The module of the configuration's model family: ``families/<name>.py``
+    for the file's ``family`` (``"llama"`` where it names none), found on the
+    ``families`` package's path.  An unknown name is an error."""
+    import families
+    name = conf.get("family", "llama")
+    if not name.isidentifier():
+        raise ValueError(f"model family {name!r} is not a module name")
+    try:
+        return importlib.import_module(f"families.{name}")
+    except ModuleNotFoundError as e:
+        if e.name != f"families.{name}":
+            raise
+        raise ValueError(f"unknown model family {name!r}: no {name}.py on "
+                         f"{list(families.__path__)}") from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,8 +2,9 @@
 
 After the window, a sample of the finished requests, drawn from the seed
 and always holding the longest reply, is run through the plain reference
-(``reference.py``) over each prompt with its served tokens.  Two numbers
-are compared, each with a limit from the cell's file:
+of the configuration's family (``cells.family``) over each prompt with its
+served tokens.  Two numbers are compared, each with a limit from the
+cell's file:
 
 * ``max_logit_gap``: the widest gap, over every served token of the
   sample, by which the reference's logit of the served token lies below the
@@ -29,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import cells
-import reference as ref
 
 
 def sample(waves, seed: int, count: int):
@@ -54,10 +54,11 @@ def compare(bench, picked, control: bool = False) -> Dict[str, object]:
     """Run the reference (and, with ``control``, the float8 control) over
     the picked requests; return the readings."""
     conf, cell = bench.conf, bench.cell
-    g = ref.Geometry(conf)
+    fam = cells.family(conf)
+    g = fam.Geometry(conf)
     bos = conf["bos_token_id"]
     ctx, qry = cells.calibration_sample(conf)
-    layers, _ = ref.selection(bench.params, g, ctx, qry, bos,
+    layers, _ = fam.selection(bench.params, g, ctx, qry, bos,
                               cell["ratio"], cell["alpha"])
     mismatch = len(set(layers) ^ set(bench.layers))
     pad_to = max(s.prefix for s in bench.sizes)
@@ -65,14 +66,14 @@ def compare(bench, picked, control: bool = False) -> Dict[str, object]:
     for req, toks in picked:
         toks = np.asarray(toks)
         served += len(toks)
-        lg = ref.hop_logits(bench.params, g, layers, bos, req.context,
+        lg = fam.hop_logits(bench.params, g, layers, bos, req.context,
                             req.query, toks, pad_to)
         if not bool(jnp.all(jnp.isfinite(lg))):
             gaps.append(np.inf)
             continue
         gaps.append(float(_gap(lg, toks).max()))
         if control:
-            cl = ref.hop_logits(bench.params, g, layers, bos, req.context,
+            cl = fam.hop_logits(bench.params, g, layers, bos, req.context,
                                 req.query, toks, pad_to, policy="fp8")
             top = np.asarray(jnp.argmax(cl, axis=-1))
             cgaps.append(float(_gap(lg, top).max()))

@@ -1,9 +1,22 @@
-"""The plain reference: a Llama-style decoder and the KVComm hop, in float32.
+"""The Llama family: a Llama-style decoder and the KVComm hop.
 
-Written from the published descriptions, not from the program: RMSNorm,
-rotary embeddings (rotate-half, base ``rope_theta``), grouped-query
-attention with softmax in float32, a SwiGLU MLP, no biases, an untied
-head.  The KVComm hop (arXiv:2510.03346, section 3) is:
+The configuration file holds the published ``config.json`` keys of a
+dense decoder with RMSNorm, rotary embeddings, grouped-query attention
+and a SwiGLU MLP (``hidden_size``, ``intermediate_size``,
+``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
+``num_hidden_layers``, ``vocab_size``, ``rope_theta``, ``rms_norm_eps``).
+
+Weights: one stacked attention run of ``num_hidden_layers`` layers with a
+dense SwiGLU ``mlp``, untied head, in the layout the program serves.
+
+Counts (``families/__init__.py``): a multiply-add is 2 FLOPs and the
+embedding lookup is free; every layer holds every key it may see.
+
+The plain reference, written from the published descriptions, not from
+the program: RMSNorm, rotary embeddings (rotate-half, base
+``rope_theta``), grouped-query attention with softmax in float32, a
+SwiGLU MLP, no biases, an untied head.  The KVComm hop (arXiv:2510.03346,
+section 3) is:
 
 * the sender runs ``[BOS] + context`` and keeps every layer's keys
   (rotated at positions ``0 .. P-1``) and values;
@@ -34,6 +47,107 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+BF16 = 2
+
+
+def model_config(conf: dict):
+    """The program's ``ModelConfig`` for a configuration file: the published
+    keys as run, plus the program options the file names."""
+    from repro.configs.base import ModelConfig
+    return ModelConfig(
+        name=conf["name"], arch_type="dense", source=conf["source"],
+        num_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        num_heads=conf["num_attention_heads"],
+        num_kv_heads=conf["num_key_value_heads"],
+        head_dim=conf["head_dim"], d_ff=conf["intermediate_size"],
+        vocab_size=conf["vocab_size"], rope_theta=float(conf["rope_theta"]),
+        norm_eps=float(conf["rms_norm_eps"]),
+        tie_embeddings=conf["tie_word_embeddings"],
+        dtype=conf["torch_dtype"], remat=False, **conf.get("program", {}))
+
+
+# -- weights -------------------------------------------------------------------
+def shapes(conf: dict) -> dict:
+    """Leaf shapes of the parameter tree of a configuration file."""
+    d, f, V = conf["hidden_size"], conf["intermediate_size"], conf["vocab_size"]
+    L, D = conf["num_hidden_layers"], conf["head_dim"]
+    Hq, Hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
+    return {
+        "embed": (V, d),
+        "final_norm": (d,),
+        "blocks": [{
+            "ln1": (L, d),
+            "attn": {"wq": (L, d, Hq * D), "wk": (L, d, Hkv * D),
+                     "wv": (L, d, Hkv * D), "wo": (L, Hq * D, d)},
+            "ln2": (L, d),
+            "mlp": {"w_gate": (L, d, f), "w_up": (L, d, f),
+                    "w_down": (L, f, d)},
+        }],
+        "lm_head": (d, V),
+    }
+
+
+# -- counts --------------------------------------------------------------------
+def layer_matmul_params(conf: dict) -> int:
+    """Weights of one layer's projections and MLP (norm gains excluded)."""
+    d, f, D = conf["hidden_size"], conf["intermediate_size"], conf["head_dim"]
+    Hq, Hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
+    return d * (Hq + 2 * Hkv) * D + Hq * D * d + 3 * d * f
+
+
+def head_flops(conf: dict) -> int:
+    return 2 * conf["hidden_size"] * conf["vocab_size"]
+
+
+def attn_flops(conf: dict, keys: int) -> int:
+    """Scores and weighted values of one query row against ``keys`` keys,
+    in one layer."""
+    return 4 * conf["num_attention_heads"] * conf["head_dim"] * keys
+
+
+def sender_prefill_flops(conf: dict, prefix: int) -> int:
+    """The sender's pass over ``prefix`` tokens (BOS included), every
+    layer, causal; no head (only keys and values leave the sender)."""
+    L = conf["num_hidden_layers"]
+    causal_keys = prefix * (prefix + 1) // 2
+    return L * (2 * layer_matmul_params(conf) * prefix
+                + attn_flops(conf, causal_keys))
+
+
+def receiver_prefill_flops(conf: dict, query: int, prefix: int,
+                           layers: Sequence[int]) -> int:
+    """The receiver's pass over ``query`` real tokens; the ``layers`` also
+    see ``prefix`` keys; the head runs once, for the first reply token."""
+    L = conf["num_hidden_layers"]
+    causal_keys = query * (query + 1) // 2
+    return (L * (2 * layer_matmul_params(conf) * query
+                 + attn_flops(conf, causal_keys))
+            + len(layers) * attn_flops(conf, query * prefix)
+            + head_flops(conf))
+
+
+def decode_row_flops(conf: dict, own: int, prefix: int,
+                     layers: Sequence[int]) -> int:
+    """One decode token of one live row that sees ``own`` keys of its own
+    (the new token included) and, at the ``layers``, ``prefix``."""
+    L = conf["num_hidden_layers"]
+    return (L * (2 * layer_matmul_params(conf) + attn_flops(conf, own))
+            + len(layers) * attn_flops(conf, prefix) + head_flops(conf))
+
+
+def decode_attn_call(conf: dict, layer: int, keys: Sequence[int]):
+    """(FLOPs, bytes) one decode-attention call requires for live rows that
+    see ``keys[i]`` keys each, at any layer: read q, the valid keys and
+    values, write the output, all in bfloat16."""
+    Hq, Hkv, D = (conf["num_attention_heads"], conf["num_key_value_heads"],
+                  conf["head_dim"])
+    n = sum(keys)
+    flops = 4 * Hq * D * n
+    nbytes = BF16 * (2 * Hkv * D * n + 2 * Hq * D * len(keys))
+    return flops, nbytes
+
+
+# -- the reference -------------------------------------------------------------
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0

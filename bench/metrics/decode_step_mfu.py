@@ -1,7 +1,7 @@
 """The ragged decode step's share of the chip's bf16 peak: the FLOPs the
-traced steps' live rows require (``counts.decode_row_flops``) over the
-step program's device time times the peak."""
-import counts
+traced steps' live rows require (the family's ``decode_row_flops``)
+over the step program's device time times the peak."""
+import cells
 
 
 def read(ctx):
@@ -9,8 +9,8 @@ def read(ctx):
     if not calls:
         return None
     b = ctx.bench
-    M = len(b.layers)
-    flops = sum(counts.decode_row_flops(b.conf, int(o), int(p), M)
+    count = cells.family(b.conf).decode_row_flops
+    flops = sum(count(b.conf, int(o), int(p), b.layers)
                 for own, pfx, live in b.step_rows()
                 for o, p, a in zip(own, pfx, live) if a)
     return 100.0 * flops / (secs * ctx.peak["bf16_flops_per_s"])

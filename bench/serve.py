@@ -90,7 +90,7 @@ class Bench:
         from repro.store import PageStore
         c, conf = self.cell, self.conf
         t = time.perf_counter()
-        self.cfg = cells.model_config(conf)
+        self.cfg = cells.family(conf).model_config(conf)
         self.params = weights.make(conf, conf["weights_seed"])
         jax.block_until_ready(self.params)
         self.phases = {"weights_s": time.perf_counter() - t}

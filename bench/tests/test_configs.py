@@ -36,7 +36,7 @@ def test_config_matches_published(name):
 @pytest.mark.parametrize("name", sorted(PUBLISHED))
 def test_model_config_keeps_every_width(name):
     conf = cells._load("configs", name)
-    cfg = cells.model_config(conf)
+    cfg = cells.family(conf).model_config(conf)
     assert (cfg.d_model, cfg.d_ff, cfg.num_heads, cfg.num_kv_heads,
             cfg.resolved_head_dim, cfg.vocab_size, cfg.num_layers) == (
         conf["hidden_size"], conf["intermediate_size"],

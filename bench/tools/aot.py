@@ -32,7 +32,7 @@ def main(names) -> None:
     from repro import core
     from repro.core.protocol import (_ragged_decode_step_jit,
                                      _receiver_prefill_jit,
-                                     _sender_prefill_jit)
+                                     _sender_prefill_jit, extract_kv)
     from repro.core.types import KVCommConfig
     from repro.models import transformer as tfm
     from repro.serving.scheduler import _insert_jit
@@ -53,7 +53,7 @@ def main(names) -> None:
         if cap:
             cell.update(capacity=int(cap), wave=2 * int(cap))
         conf = cell["config_file"]
-        cfg = cells.model_config(conf)
+        cfg = cells.family(conf).model_config(conf)
         kvcfg = KVCommConfig(ratio=cell["ratio"], alpha=cell["alpha"],
                              selector="prior_only")
         select = core.make_selection(cfg, kvcfg)
@@ -65,12 +65,12 @@ def main(names) -> None:
         qmax = -(-max(s.query for s in sizes) // qb) * qb
         budget = max(s.max_new for s in sizes) - 1
         cap = cell["capacity"]
-        dt = jnp.dtype(cfg.dtype)
-        Hkv, D = cfg.num_kv_heads, cfg.resolved_head_dim
+        idx = jnp.asarray(layers, jnp.int32)
 
         def shared_of(B, S):
-            payload = {p: jnp.zeros((len(layers), B, S, Hkv, D), dt)
-                       for p in ("k", "v")}
+            # the selected layers of what the sender exports at length S
+            kv = extract_kv(cfg, tfm.init_cache(cfg, B, S))
+            payload = {p: kv[p][idx] for p in ("k", "v")}
             return core.build_packed(kvcfg, payload, layers, S,
                                      select=select)
 
